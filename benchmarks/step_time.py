@@ -33,11 +33,14 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.configs as C
+from repro.core.peaks import PEAKS, V5E
 from repro.data import pipeline
-from repro.launch.hlo_analysis import LINK_BW, PEAK_FLOPS
 from repro.models.config import ShapeConfig
 from repro.train import optimizer as opt_lib
 from repro.train import train_step as train_lib
+
+LINK_BW = PEAKS[V5E].ici_link_bw        # the modelled chip is a v5e
+PEAK_FLOPS = PEAKS[V5E].bf16_flops
 
 
 # ------------------------------------------------------- HBM-bytes model
@@ -111,7 +114,7 @@ def measured_train_step():
                         microbatch=2)
     opt_cfg = opt_lib.OptConfig(warmup_steps=2, total_steps=100)
     batch = pipeline.DataIterator(cfg, shape).batch(0)
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
     out = {}
     for label, kw in (("serial", {}),
                       ("overlap", {"overlap_comm": True, "mesh": mesh})):

@@ -307,7 +307,8 @@ class ClusterController:
             self.monitor.set_roofline(
                 blk.block_id,
                 hlo_analysis.block_roofline(job.cfg, job.shape,
-                                            len(blk.grant.coords)))
+                                            len(blk.grant.coords),
+                                            rt.devices[0].device_kind))
         except Exception:
             pass    # monitoring garnish: never block activation on it
 
@@ -343,6 +344,9 @@ class ClusterController:
             drain = getattr(rt, "drain", None)
             if drain is not None:
                 drain()
+            release = getattr(rt, "release", None)
+            if release is not None:
+                release()
         if blk.grant:
             self.partitioner.release(blk.grant.block_id)
         self.registry.set_state(app_id, BlockState.EXPIRED, "period over")

@@ -292,8 +292,11 @@ class ClusterDaemon:
                         self.engine.run_round(pod=pod_id)
                         busy = self.engine.last_round_busy
                     except Exception:
-                        # an engine bug must not kill the worker — but it
-                        # must not busy-spin or fail silently either
+                        # a block's own step error already failed that
+                        # block inside the round (AutostepEngine.
+                        # _fail_block); what lands here is an engine bug,
+                        # which must not kill the worker — but must not
+                        # busy-spin or fail silently either
                         self.engine.last_round_busy = False
                         if not self._engine_error_logged:
                             self._engine_error_logged = True

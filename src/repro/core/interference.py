@@ -13,9 +13,10 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List, Sequence, Tuple
 
+from repro.core.peaks import PEAKS, V5E
 from repro.core.topology import (Coord, Link, Topology, min_bisection_links)
 
-LINK_BW = 50e9          # bytes/s per ICI link (v5e)
+LINK_BW = PEAKS[V5E].ici_link_bw   # the modelled fabric is a v5e pod's
 DCN_BW = 25e9           # bytes/s inter-pod (abstract pod link)
 
 

@@ -110,6 +110,9 @@ class BlockRuntime(InflightWindow):
                          ("data", "model"))
         self.axes = plans.MeshAxes(dp=("data",), model="model")
         self.ctx = shard_ctx.ShardCtx(self.mesh, ("data",), "model")
+        # arrays the block makes outside its step (caches, page pool,
+        # decode inputs) live replicated on the block's own chips
+        self.replicated = NamedSharding(self.mesh, P())
         self._build()
 
     # ------------------------------------------------------------ compile
@@ -215,11 +218,13 @@ class BlockRuntime(InflightWindow):
                 self.sessions = self._make_scheduler(params)
                 self.token = self.sessions.last_tokens_dev
                 return
-            cache = model_lib.init_cache(job.cfg, job.shape.global_batch,
-                                         job.shape.seq_len)
-            self.cache = cache
-            self.cache_len = jnp.int32(0)
-            self.token = jnp.zeros((job.shape.global_batch, 1), jnp.int32)
+            B, S = job.shape.global_batch, job.shape.seq_len
+            self.cache = jax.jit(
+                lambda: model_lib.init_cache(job.cfg, B, S),
+                out_shardings=self.replicated)()
+            self.cache_len = jax.device_put(np.int32(0), self.replicated)
+            self.token = jax.device_put(np.zeros((B, 1), np.int32),
+                                        self.replicated)
 
     def _paged_geometry(self) -> Dict[str, int]:
         job = self.job
@@ -232,6 +237,7 @@ class BlockRuntime(InflightWindow):
         job = self.job
         return DecodeScheduler(job.cfg, params, sample=job.decode_sample,
                                seed=job.seed, init_pool=init_pool,
+                               sharding=self.replicated,
                                **self._paged_geometry())
 
     def prefill(self, batch: Dict[str, Any]) -> None:
@@ -259,7 +265,8 @@ class BlockRuntime(InflightWindow):
         logits, self.cache = self._prefill_fn(self.state["params"], batch,
                                               self.cache)
         self.token = jnp.argmax(logits, -1)[:, None].astype(jnp.int32)
-        self.cache_len = jnp.int32(batch["tokens"].shape[1])
+        self.cache_len = jax.device_put(np.int32(batch["tokens"].shape[1]),
+                                        self.replicated)
 
     # ------------------------------------------------- generate sessions
     # (paged serve only: the continuous-batching session surface the
@@ -414,6 +421,15 @@ class BlockRuntime(InflightWindow):
             self.ckpt.save(self.step_count, payload)
         self.last_saved_step = self.step_count
 
+    def release(self) -> None:
+        """Expiry: drop every device array the block holds (state, caches,
+        the paged pool) so its chips' memory is free before they are
+        granted again.  Call after ``drain()``."""
+        self.state = None
+        self.cache = None
+        self.sessions = None
+        self.token = None
+
     @property
     def progress_lost(self) -> int:
         """Steps of work beyond the last checkpoint — what a *non-graceful*
@@ -470,9 +486,10 @@ class BlockRuntime(InflightWindow):
             decode_like = (self._decode_ctx() if have_ctx
                            else self._abstract_decode())
             like["decode"] = decode_like
-            # decode context restores to default placement (the same the
-            # init path uses); None per leaf keeps the trees congruent
-            shardings["decode"] = jax.tree.map(lambda _: None, decode_like)
+            # the decode context restores onto the block's own chips, as
+            # the init path places it
+            shardings["decode"] = jax.tree.map(lambda _: self.replicated,
+                                               decode_like)
         restored, at = self.ckpt.restore(like, step=step, shardings=shardings)
         self.state = restored["state"]
         if self.job.kind == "serve":

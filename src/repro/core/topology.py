@@ -101,6 +101,25 @@ class Topology:
         return use
 
 
+def host_topology(devices: Sequence) -> Tuple[Topology, List]:
+    """One host's chips as a one-pod topology in their physical layout (a
+    v5e 4-chip host is 2x2), with the devices reordered so chip
+    ``(0, x, y)`` is the device at grid position ``(x, y)``.  Devices that
+    report no grid coordinates (the CPU backend) form an ``n x 1`` line."""
+    devices = list(devices)
+    coords = [getattr(d, "coords", None) for d in devices]
+    if any(c is None for c in coords):
+        return Topology(n_pods=1, pod_x=len(devices), pod_y=1), devices
+    xs = sorted({c[0] for c in coords})
+    ys = sorted({c[1] for c in coords})
+    at = {(xs.index(c[0]), ys.index(c[1])): d
+          for c, d in zip(coords, devices)}
+    if len(at) != len(devices) or len(xs) * len(ys) != len(devices):
+        raise ValueError(f"devices do not tile a grid: {coords}")
+    topo = Topology(n_pods=1, pod_x=len(xs), pod_y=len(ys))
+    return topo, [at[(x, y)] for x in range(len(xs)) for y in range(len(ys))]
+
+
 def rect_coords(pod: int, x0: int, y0: int, w: int, h: int) -> List[Coord]:
     return [(pod, x, y) for x in range(x0, x0 + w) for y in range(y0, y0 + h)]
 

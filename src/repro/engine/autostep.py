@@ -142,6 +142,17 @@ class AutostepEngine:
                              steps_driven=drive.steps_driven)
         return True
 
+    def _fail_block(self, app_id: str, exc: Exception,
+                    now: Optional[float]) -> None:
+        """A block's step raised (a kernel the compiler refused, device
+        OOM, a bad batch): that block goes FAILED, which the flight
+        recorder dumps, and leaves the engine.  Every other block keeps
+        running; the error is the block's, not the engine's."""
+        reason = f"step failed: {type(exc).__name__}: {exc}"
+        self.disable(app_id, reason="block failed", now=now)
+        self.ctl.registry.get(app_id).failure_reason = reason
+        self.ctl.registry.set_state(app_id, BlockState.FAILED, reason[:500])
+
     def set_pace(self, app_id: str, max_rate_hz: Optional[float],
                  now: Optional[float] = None) -> Dict:
         drive = self._drives.get(app_id)
@@ -338,13 +349,18 @@ class AutostepEngine:
             # harvest under a per-app span that joins the block's *bound*
             # trace (the request that bound it), not the worker thread's
             # incidental stack — see Tracer.span(parent="binding")
-            with TRACER.span("engine.harvest", cat="engine", app_id=app_id,
-                             parent="binding"):
-                for rec in rt.poll(block=False):
-                    self._publish_step(app_id, drive, rec, now)
-                    work += 1
-                work += self._harvest_generate(app_id, drive, rt, now)
-            self._maybe_checkpoint(drive, rt)
+            try:
+                with TRACER.span("engine.harvest", cat="engine",
+                                 app_id=app_id, parent="binding"):
+                    for rec in rt.poll(block=False):
+                        self._publish_step(app_id, drive, rec, now)
+                        work += 1
+                    work += self._harvest_generate(app_id, drive, rt, now)
+                self._maybe_checkpoint(drive, rt)
+            except Exception as e:
+                self._fail_block(app_id, e, now)
+                work += 1
+                continue
             cfg = drive.config
             if cfg.until_steps is not None and \
                     rt.step_count >= cfg.until_steps:
@@ -417,11 +433,18 @@ class AutostepEngine:
         for view in views:
             self._drives[view.app_id].deficit = view.deficit
         for app_id in plan:
+            if app_id not in self._drives:
+                continue             # failed earlier in this round
             # paged serve decode rounds run synchronously inside
             # dispatch(), so their spans nest under this one
-            with TRACER.span("engine.dispatch", cat="engine",
-                             app_id=app_id, parent="binding"):
-                runnable[app_id].dispatch()
+            try:
+                with TRACER.span("engine.dispatch", cat="engine",
+                                 app_id=app_id, parent="binding"):
+                    runnable[app_id].dispatch()
+            except Exception as e:
+                self._fail_block(app_id, e, now)
+                work += 1
+                continue
             drive = self._drives[app_id]
             if app_id in rated:
                 drive.allowance -= 1.0

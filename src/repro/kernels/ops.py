@@ -2,22 +2,27 @@
 
 Each op has up to three implementations:
   * ``jnp``    — chunked, O(S*chunk)-memory pure-jnp path.  This is what the
-                 models lower through in the CPU dry-run and what real TPU runs
-                 fall back to when Pallas is disabled.
+                 models lower through on the CPU and in multi-chip blocks.
   * ``pallas`` — the TPU kernel (``flash_attention.py`` / ``rmsnorm.py`` /
                  ``ssd_scan.py``), validated on CPU via interpret mode.
   * ``ref``    — naive oracle in ``ref.py`` (tests only).
 
-``impl='auto'`` picks pallas on TPU backends and jnp elsewhere.
+``impl='auto'`` picks pallas on TPU backends and jnp elsewhere, with one
+exception: inside a block whose mesh spans several chips the Pallas calls
+would need manual partitioning (GSPMD cannot split a Mosaic kernel), so
+such blocks lower every op through XLA and say so once with a warning.
 """
 from __future__ import annotations
 
 import functools
+import warnings
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.sharding import ctx as shard_ctx
 
 NEG_INF = -1.0e30
 
@@ -27,7 +32,14 @@ def _use_pallas(impl: str) -> bool:
         return True
     if impl == "jnp":
         return False
-    return jax.default_backend() == "tpu"
+    if jax.default_backend() != "tpu":
+        return False
+    ctx = shard_ctx.current()
+    if ctx is not None and ctx.mesh.size > 1:
+        warnings.warn(f"a {ctx.mesh.size}-chip block lowers its kernels "
+                      f"through XLA: Pallas kernels run on 1-chip blocks")
+        return False
+    return True
 
 
 # ===========================================================================
@@ -42,14 +54,40 @@ def flash_attention(q, k, v, *, causal: bool = True, sliding_window: int = 0,
 
     q: (B, Hq, Sq, D); k: (B, Hkv, Sk, D); v: (B, Hkv, Sk, Dv).
     """
-    if _use_pallas(impl):
-        from repro.kernels import flash_attention as _fa
-        return _fa.flash_attention_pallas(
-            q, k, v, causal=causal, sliding_window=sliding_window, scale=scale,
-            q_offset=q_offset, interpret=(jax.default_backend() != "tpu"))
     del q_chunk  # full-q tiles per kv chunk in the jnp path
+    if _use_pallas(impl):
+        return _flash_pallas(q, k, v, causal, sliding_window, scale,
+                             q_offset, kv_chunk)
     return _flash_jnp(q, k, v, causal, sliding_window, scale, q_offset,
                       kv_chunk)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_pallas(q, k, v, causal, sliding_window, scale, q_offset,
+                  kv_chunk):
+    """Pallas forward; the backward is the jnp flash backward, with the
+    row log-sum-exp recomputed in XLA (a pallas_call has no derivative of
+    its own)."""
+    from repro.kernels import flash_attention as _fa
+    return _fa.flash_attention_pallas(
+        q, k, v, causal=causal, sliding_window=sliding_window, scale=scale,
+        q_offset=q_offset, interpret=(jax.default_backend() != "tpu"))
+
+
+def _flash_pallas_fwd(q, k, v, causal, window, scale, q_offset, kv_chunk):
+    o = _flash_pallas(q, k, v, causal, window, scale, q_offset, kv_chunk)
+    return o, (q, k, v, o)
+
+
+def _flash_pallas_bwd(causal, window, scale, q_offset, kv_chunk, res, do):
+    q, k, v, o = res
+    _, lse = _flash_fwd_impl(q, k, v, causal, window, scale, q_offset,
+                             kv_chunk)
+    return _flash_bwd_rule(causal, window, scale, q_offset, kv_chunk,
+                           (q, k, v, o, lse), do)
+
+
+_flash_pallas.defvjp(_flash_pallas_fwd, _flash_pallas_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -242,12 +280,37 @@ def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *,
 
 def rmsnorm(x, scale, *, eps: float = 1e-6, impl: str = "auto"):
     if _use_pallas(impl):
-        from repro.kernels import rmsnorm as _rn
-        return _rn.rmsnorm_pallas(x, scale, eps=eps,
-                                  interpret=(jax.default_backend() != "tpu"))
+        return _rmsnorm_pallas(x, scale, eps)
+    return _rmsnorm_jnp(x, scale, eps)
+
+
+def _rmsnorm_jnp(x, scale, eps):
     xf = x.astype(jnp.float32)
     var = jnp.mean(xf * xf, axis=-1, keepdims=True)
     return ((xf * jax.lax.rsqrt(var + eps)) * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _rmsnorm_pallas(x, scale, eps):
+    """Pallas forward; the backward recomputes the norm in XLA (a
+    pallas_call has no derivative of its own, and the train step
+    differentiates through every norm)."""
+    from repro.kernels import rmsnorm as _rn
+    return _rn.rmsnorm_pallas(x, scale, eps=eps,
+                              interpret=(jax.default_backend() != "tpu"))
+
+
+def _rmsnorm_pallas_fwd(x, scale, eps):
+    return _rmsnorm_pallas(x, scale, eps), (x, scale)
+
+
+def _rmsnorm_pallas_bwd(eps, res, g):
+    x, scale = res
+    _, vjp = jax.vjp(lambda x_, s_: _rmsnorm_jnp(x_, s_, eps), x, scale)
+    return vjp(g)
+
+
+_rmsnorm_pallas.defvjp(_rmsnorm_pallas_fwd, _rmsnorm_pallas_bwd)
 
 
 # ===========================================================================

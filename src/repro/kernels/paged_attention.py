@@ -1,4 +1,4 @@
-"""Pallas paged-attention gather kernel (single-token decode, GQA).
+"""Pallas paged-attention kernel (single-token decode, GQA).
 
 Continuous-batching serve blocks keep every live session's K/V in one
 block-granular *page pool* instead of a per-sequence ``smax`` allocation:
@@ -9,20 +9,25 @@ block-granular *page pool* instead of a per-sequence ``smax`` allocation:
 
 Sequence position ``p`` of slot ``b`` lives at row ``p % page_size`` of page
 ``page_table[b, p // page_size]``, so the gathered rows ``[0, seq_lens[b])``
-reproduce the dense cache layout exactly and decode attention stays the same
-masked softmax the dense path uses — just fetched page by page out of the
-pool rather than sliced from a contiguous per-sequence buffer.
+reproduce the dense cache layout and decode attention is the same masked
+softmax the dense path computes, fetched page by page out of the pool.
 
-Kernel structure: grid ``(B, pages_per_seq)`` with the page sweep minor-most.
-``page_table`` and ``seq_lens`` ride scalar prefetch
-(``pltpu.PrefetchScalarGridSpec``) so the K/V BlockSpec index maps chase the
-page table when scheduling block DMAs — the gather happens in the pipeline,
-not as a materialized (B, S, ...) copy.  Each sweep stages the slot's pages
-into VMEM scratch (persistent across the minor grid dim, like
-``flash_attention``'s accumulators); the final step applies the *identical*
-op sequence as ``ref.attention`` (fp32 einsum -> masked softmax -> fp32
-einsum), so interpret mode matches the reference bit-for-bit — tests assert
-``array_equal``, not ``allclose``.
+Kernel structure: grid ``(B, pages_per_seq)`` with the page sweep
+minor-most.  ``page_table`` and ``seq_lens`` ride scalar prefetch
+(``pltpu.PrefetchScalarGridSpec``) so the K/V BlockSpec index maps chase
+the page table when scheduling block DMAs: the gather happens in the
+pipeline, never as a materialized (B, S, ...) copy.  Pages past a slot's
+last live page map to that last page again, so the pipeline skips their
+DMA, and ``pl.when`` skips their compute.
+
+Each page folds into an online softmax (running max ``m``, normalizer
+``l`` and fp32 accumulator per query group, in VMEM scratch that persists
+across the page sweep, as ``flash_attention`` does across kv blocks).  The
+query arrives group-major, ``(B, G, Hkv, D)``, so every array in the kernel
+keeps the page's ``(rows, Hkv, D)`` layout: scores are a lane reduction
+over ``D`` and the weighted sum a reduction over the page rows, with no
+batched dot and no transpose for Mosaic to refuse.  VMEM holds one page of
+K and V per step, whatever the sequence length.
 """
 from __future__ import annotations
 
@@ -38,35 +43,43 @@ NEG_INF = -1.0e30   # TPU-safe -inf stand-in (same convention as flash_attention
 
 
 def _paged_attention_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref,
-                            k_scr, v_scr, *, pages_per_seq: int,
-                            scale: float):
+                            m_scr, l_scr, acc_scr, *, pages_per_seq: int,
+                            page: int, groups: int, scale: float):
     b = pl.program_id(0)
     j = pl.program_id(1)
+    seq_len = sl_ref[b]
 
-    # stage this page of the slot's K/V into the persistent VMEM scratch
-    k_scr[j] = k_ref[0]
-    v_scr[j] = v_ref[0]
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * page < seq_len)
+    def _body():
+        k = k_ref[0].astype(jnp.float32)                   # (page, Hkv, D)
+        v = v_ref[0].astype(jnp.float32)                   # (page, Hkv, Dv)
+        pos = j * page + jax.lax.broadcasted_iota(
+            jnp.int32, (page, k.shape[1], 1), 0)
+        live = pos < seq_len                               # (page, Hkv, 1)
+        for g in range(groups):
+            q = q_ref[0, g].astype(jnp.float32)            # (Hkv, D)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            s = jnp.where(live, s, NEG_INF)                # (page, Hkv, 1)
+            m_prev = m_scr[g]                              # (Hkv, 1)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None]) * live.astype(jnp.float32)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[g] = l_scr[g] * corr + jnp.sum(p, axis=0)
+            acc_scr[g] = acc_scr[g] * corr + jnp.sum(p * v, axis=0)
+            m_scr[g] = m_new
 
     @pl.when(j == pages_per_seq - 1)
     def _finalize():
-        page, Hkv, D = k_scr.shape[1:]
-        Dv = v_scr.shape[-1]
-        S = pages_per_seq * page
-        Hq = q_ref.shape[1]
-        G = Hq // Hkv
-        # mirror ref.attention's exact shapes/ops (B=1, Sq=1): fp32 scores,
-        # length-masked softmax, fp32 weighted sum — bitwise identical in
-        # interpret mode
-        qf = q_ref[...].astype(jnp.float32).reshape(1, Hkv, G, 1, D)
-        kf = k_scr[...].reshape(1, S, Hkv, D).swapaxes(1, 2).astype(jnp.float32)
-        vf = v_scr[...].reshape(1, S, Hkv, Dv).swapaxes(1, 2).astype(jnp.float32)
-        s = jnp.einsum("bhgqd,bhkd->bhgqk", qf, kf) * scale
-        k_pos = jax.lax.broadcasted_iota(jnp.int32, (S,), 0)
-        mask = k_pos < sl_ref[b]
-        s = jnp.where(mask[None, None, None, :], s, NEG_INF)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhgqk,bhkd->bhgqd", p, vf)
-        o_ref[...] = o.reshape(1, Hq, Dv).astype(o_ref.dtype)
+        for g in range(groups):
+            l = l_scr[g]
+            l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0, g] = (acc_scr[g] / l).astype(o_ref.dtype)
 
 
 def paged_attention_pallas(q, k_pages, v_pages, page_table, seq_lens, *,
@@ -83,30 +96,42 @@ def paged_attention_pallas(q, k_pages, v_pages, page_table, seq_lens, *,
     B, Hq, D = q.shape
     _, page, Hkv, _ = k_pages.shape
     Dv = v_pages.shape[-1]
+    G = Hq // Hkv
     maxp = page_table.shape[1]
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
+    # head h*G + g of the query serves kv head h: group-major (B, G, Hkv, D)
+    qg = q.reshape(B, Hkv, G, D).swapaxes(1, 2)
+
+    def kv_index(b, j, pt_ref, sl_ref):
+        # pages past the slot's last live page re-name that page: an
+        # unchanged block index means the pipeline starts no new DMA
+        last = jnp.maximum(sl_ref[b] - 1, 0) // page
+        return (pt_ref[b, jnp.minimum(j, last)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, maxp),
         in_specs=[
-            pl.BlockSpec((1, Hq, D), lambda b, j, *_: (b, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, D),
-                         lambda b, j, pt_ref, sl_ref: (pt_ref[b, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, Hkv, Dv),
-                         lambda b, j, pt_ref, sl_ref: (pt_ref[b, j], 0, 0, 0)),
+            pl.BlockSpec((1, G, Hkv, D), lambda b, j, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page, Hkv, D), kv_index),
+            pl.BlockSpec((1, page, Hkv, Dv), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, Hq, Dv), lambda b, j, *_: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, G, Hkv, Dv), lambda b, j, *_: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((maxp, page, Hkv, D), k_pages.dtype),
-            pltpu.VMEM((maxp, page, Hkv, Dv), v_pages.dtype),
+            pltpu.VMEM((G, Hkv, 1), jnp.float32),
+            pltpu.VMEM((G, Hkv, 1), jnp.float32),
+            pltpu.VMEM((G, Hkv, Dv), jnp.float32),
         ],
     )
-    kernel = functools.partial(_paged_attention_kernel,
-                               pages_per_seq=maxp, scale=scale)
-    return pl.pallas_call(
+    kernel = functools.partial(_paged_attention_kernel, pages_per_seq=maxp,
+                               page=page, groups=G, scale=scale)
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, Dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, G, Hkv, Dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table, seq_lens, q, k_pages, v_pages)
+        name="paged_attention",
+    )(page_table, seq_lens, qg, k_pages, v_pages)
+    return out.swapaxes(1, 2).reshape(B, Hq, Dv)

@@ -16,13 +16,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 
-def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float, n_rows: int,
-                    block_rows: int):
-    i = pl.program_id(0)
+def _rmsnorm_kernel(x_ref, s_ref, o_ref, *, eps: float):
     x = x_ref[...].astype(jnp.float32)                 # (block_rows, d)
     var = jnp.mean(x * x, axis=-1, keepdims=True)
     y = x * jax.lax.rsqrt(var + eps) * s_ref[...].astype(jnp.float32)
-    # mask padded rows (beyond n_rows) — harmless garbage, sliced off outside
+    # padded rows (beyond the real row count) are harmless garbage, sliced off outside
     o_ref[...] = y.astype(o_ref.dtype)
 
 
@@ -37,8 +35,7 @@ def rmsnorm_pallas(x, scale, *, eps: float = 1e-6, block_rows: int = 256,
     rows_pad = -(-rows // block_rows) * block_rows
     xf = jnp.pad(xf, ((0, rows_pad - rows), (0, 0)))
     grid = (rows_pad // block_rows,)
-    kernel = functools.partial(_rmsnorm_kernel, eps=eps, n_rows=rows,
-                               block_rows=block_rows)
+    kernel = functools.partial(_rmsnorm_kernel, eps=eps)
     out = pl.pallas_call(
         kernel,
         grid=grid,

@@ -25,6 +25,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import repro.configs as configs
+from repro.core.peaks import V5E
 from repro.data import pipeline
 from repro.launch import hlo_analysis
 from repro.launch.mesh import make_production_mesh
@@ -200,6 +201,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     compiled = lowered.compile()
     t_compile = time.time() - t0
     roof = hlo_analysis.analyze(compiled, n_chips=meta["n_chips"],
+                                device_kind=V5E,
                                 model_flops=meta["model_flops"])
     mem_report = {}
     try:

@@ -13,10 +13,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-# TPU v5e hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-LINK_BW = 50e9               # bytes/s per ICI link
+from repro.core import peaks as peaks_lib
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2,
@@ -81,20 +78,22 @@ class Roofline:
     hlo_bytes: float             # total HBM bytes accessed across devices
     collective_bytes: float      # summed collective operand bytes (per device program)
     n_chips: int
+    peaks: peaks_lib.ChipPeaks   # the target chip's (``core.peaks``)
     model_flops: float = 0.0
     bytes_per_device: float = 0.0
 
     @property
     def compute_s(self) -> float:
-        return self.hlo_flops / (self.n_chips * PEAK_FLOPS)
+        return self.hlo_flops / (self.n_chips * self.peaks.bf16_flops)
 
     @property
     def memory_s(self) -> float:
-        return self.hlo_bytes / (self.n_chips * HBM_BW)
+        return self.hlo_bytes / (self.n_chips * self.peaks.hbm_bw)
 
     @property
     def collective_s(self) -> float:
-        return self.collective_bytes / (self.n_chips * LINK_BW)
+        return self.collective_bytes / (self.n_chips
+                                       * self.peaks.ici_link_bw)
 
     @property
     def bottleneck(self) -> str:
@@ -116,7 +115,7 @@ class Roofline:
         """Fraction of the compute roofline achieved at the modeled step time:
         useful (model) FLOPs / (step_time * peak).  1.0 = compute-bound with
         zero waste."""
-        denom = self.step_time_s * self.n_chips * PEAK_FLOPS
+        denom = self.step_time_s * self.n_chips * self.peaks.bf16_flops
         return self.model_flops / denom if denom else 0.0
 
     xla_cost: Optional[Dict] = None
@@ -157,19 +156,25 @@ def model_step_flops(cfg, shape) -> float:
     return 2.0 * n_eff * shape.global_batch      # decode: one token per seq
 
 
-def block_roofline(cfg, shape, n_chips: int) -> Dict:
+def block_roofline(cfg, shape, n_chips: int, device_kind: str) -> Dict:
     """Roofline model for a live block, for ``Monitor.set_roofline``.
 
     Prefers the dry-run artifact for this (arch, shape) cell — the full
     compute/memory/collective model from the compiled HLO — and falls back
     to the analytic compute-bound floor (model FLOPs / chips x peak) when no
-    sweep has been run, so every block always carries an MFU denominator.
+    sweep has been run.  A ``device_kind`` missing from ``core.peaks`` has
+    no peak: the model then carries no ``peak_flops`` and no step-time
+    floor, so the block reports no MFU.
     """
     flops = model_step_flops(cfg, shape)
     out = {"model_flops": flops, "n_chips": int(n_chips),
-           "peak_flops": PEAK_FLOPS, "source": "analytic",
-           "step_time_s": flops / (max(1, n_chips) * PEAK_FLOPS),
+           "device_kind": device_kind, "source": "analytic",
            "bottleneck": "compute"}
+    peaks = peaks_lib.lookup(device_kind)
+    if peaks is None:
+        return out
+    out.update(peak_flops=peaks.bf16_flops,
+               step_time_s=flops / (max(1, n_chips) * peaks.bf16_flops))
     cell = dryrun_roofline(getattr(cfg, "name", None),
                            getattr(shape, "name", None))
     if cell:
@@ -216,15 +221,17 @@ def dryrun_roofline(arch: Optional[str],
     return best
 
 
-def analyze(compiled, *, n_chips: int, model_flops: float = 0.0) -> Roofline:
+def analyze(compiled, *, n_chips: int, device_kind: str,
+            model_flops: float = 0.0) -> Roofline:
     """Roofline terms from a compiled SPMD executable.
 
     The compiled program is per-device; the trip-count-aware HLO walker
     (``hlo_parse``) supplies per-device flops / HBM bytes / collective operand
     bytes, which are scaled by ``n_chips`` into global quantities.  The three
-    roofline terms then divide by (chips x per-chip peak), i.e. they equal the
-    per-device time under perfect balance.  XLA's own ``cost_analysis()``
-    counts while bodies once and is kept only as a cross-check field.
+    roofline terms then divide by (chips x the ``device_kind``'s per-chip
+    peak), i.e. they equal the per-device time under perfect balance.
+    XLA's own ``cost_analysis()`` counts while bodies once and is kept only
+    as a cross-check field.
     """
     from repro.launch import hlo_parse
     text = compiled.as_text()
@@ -248,7 +255,8 @@ def analyze(compiled, *, n_chips: int, model_flops: float = 0.0) -> Roofline:
     r = Roofline(hlo_flops=costs.flops * n_chips,
                  hlo_bytes=costs.hbm_bytes * n_chips,
                  collective_bytes=costs.total_coll_bytes * n_chips,
-                 n_chips=n_chips, model_flops=model_flops,
+                 n_chips=n_chips, peaks=peaks_lib.peaks_for(device_kind),
+                 model_flops=model_flops,
                  bytes_per_device=bpd)
     r.xla_cost = xla_cost
     r.coll_detail = {"bytes_by_kind": dict(costs.coll_bytes),

@@ -19,9 +19,10 @@ import numpy as np
 import repro.configs as configs
 from repro.core.daemon import ClusterDaemon
 from repro.core.runtime import JobSpec
-from repro.core.topology import Topology
+from repro.core.topology import host_topology
 from repro.data import pipeline
 from repro.models.config import ShapeConfig
+from repro.train import compile_cache
 
 
 def main(argv=None) -> int:
@@ -34,6 +35,7 @@ def main(argv=None) -> int:
     ap.add_argument("--sample", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.use_persistent_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))
@@ -41,9 +43,10 @@ def main(argv=None) -> int:
         raise SystemExit("encoder-only arch has no decode path")
     B, P, G = args.batch, args.prompt_len, args.gen
 
-    n_dev = len(jax.devices())
-    topo = Topology(n_pods=1, pod_x=n_dev, pod_y=1)
-    daemon = ClusterDaemon(topo, ckpt_root="artifacts/serve_ckpt")
+    topo, devices = host_topology(jax.devices())
+    n_dev = topo.n_chips
+    daemon = ClusterDaemon(topo, devices=devices,
+                           ckpt_root="artifacts/serve_ckpt")
     # cache sized for prompt + generation; the block's decode step and
     # (lazy) prefill both compile on its granted sub-mesh
     job = JobSpec(cfg, ShapeConfig("cli", "serve", seq_len=P + G,

@@ -18,9 +18,10 @@ import jax
 import repro.configs as configs
 from repro.core.daemon import ClusterDaemon
 from repro.core.runtime import JobSpec
-from repro.core.topology import Topology
+from repro.core.topology import host_topology
 from repro.models import model as model_lib
 from repro.models.config import ShapeConfig
+from repro.train import compile_cache
 from repro.train import optimizer as opt_lib
 
 
@@ -47,6 +48,7 @@ def main(argv=None) -> int:
                     help="with --autostep: cap the engine at this many "
                          "steps/s")
     args = ap.parse_args(argv)
+    compile_cache.use_persistent_cache()
 
     cfg = (configs.get_smoke(args.arch) if args.smoke
            else configs.get(args.arch))
@@ -59,9 +61,9 @@ def main(argv=None) -> int:
 
     # one block spanning every available device, granted by the daemon
     # (--autostep needs the background pump: the engine steps from there)
-    n_dev = len(jax.devices())
-    topo = Topology(n_pods=1, pod_x=n_dev, pod_y=1)
-    daemon = ClusterDaemon(topo,
+    topo, devices = host_topology(jax.devices())
+    n_dev = topo.n_chips
+    daemon = ClusterDaemon(topo, devices=devices,
                            ckpt_root=args.ckpt_dir or "artifacts/train_ckpt",
                            background=args.autostep)
     job = JobSpec(cfg, shape, opt=opt_cfg, seed=args.seed,
@@ -112,9 +114,21 @@ def main(argv=None) -> int:
         from repro.core.block import BlockState
         daemon.autostep_enable(app_id, until_steps=args.steps,
                                max_rate_hz=args.pace)
-        while daemon.registry.get(app_id).state not in (
-                BlockState.DONE, BlockState.FAILED, BlockState.EXPIRED):
+        blk = daemon.registry.get(app_id)
+        # the block's lease is the run's deadline (the pump expires it)
+        while blk.state not in (BlockState.DONE, BlockState.FAILED,
+                                BlockState.EXPIRED):
+            if time.time() > blk.grant.expires_at + 60.0:
+                print(f"# block {app_id} still {blk.state.value} past its "
+                      f"lease", flush=True)
+                daemon.stop()
+                return 1
             time.sleep(0.1)
+        if blk.state is not BlockState.DONE:
+            print(f"# block {app_id} ended {blk.state.value}: "
+                  f"{blk.failure_reason}", flush=True)
+            daemon.stop()
+            return 1
         if args.ckpt_dir and args.ckpt_every:
             daemon.save(app_id, async_=True)   # final-step checkpoint
     else:

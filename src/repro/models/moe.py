@@ -137,7 +137,6 @@ def _shardmapped(fn, args, **kw):
     context is installed; direct call otherwise (single-device tests)."""
     import functools
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
     ctx = shard_ctx.current()
     if ctx is None:
         return fn(*args, **kw)
@@ -147,7 +146,7 @@ def _shardmapped(fn, args, **kw):
         P(dp, *([None] * (a.ndim - 1))) if i == 0
         else P(None, dp, *([None] * (a.ndim - 2)))
         for i, a in enumerate(args))
-    f = shard_map(functools.partial(fn, **kw), mesh=ctx.mesh,
+    f = jax.shard_map(functools.partial(fn, **kw), mesh=ctx.mesh,
                   in_specs=in_specs, out_specs=P(dp, None, None),
                   axis_names=set(ctx.dp))
     return f(*args)

@@ -145,10 +145,13 @@ class DecodeScheduler:
     def __init__(self, cfg: ModelConfig, params, *, page_size: int = 16,
                  n_pages: int = 0, max_slots: int = 8, max_seq_len: int = 128,
                  sample: bool = False, seed: int = 0, time_fn=time.monotonic,
-                 init_pool: bool = True):
+                 init_pool: bool = True, sharding=None):
+        """``sharding``: where the pool and every decode input live (the
+        serve block's own chips); None keeps JAX's default device."""
         model_lib.check_paged_support(cfg)
         self.cfg = cfg
         self.params = params
+        self.sharding = sharding
         geo = paged_geometry(cfg, page_size=page_size, n_pages=n_pages,
                              max_slots=max_slots, max_seq_len=max_seq_len)
         self.page_size = geo["page_size"]
@@ -161,10 +164,11 @@ class DecodeScheduler:
         self._key = jax.random.PRNGKey(seed + 17)
 
         # device state
-        self.pool = (model_lib.init_paged_cache(cfg, self.n_pages,
-                                                self.page_size)
-                     if init_pool else None)
-        self.last_tokens_dev = jnp.zeros((self.max_slots, 1), jnp.int32)
+        self.pool = (jax.jit(lambda: model_lib.init_paged_cache(
+            cfg, self.n_pages, self.page_size),
+            out_shardings=sharding)() if init_pool else None)
+        self.last_tokens_dev = self._put(
+            np.zeros((self.max_slots, 1), np.int32))
         # host mirrors pushed to device each decode round
         self.page_table = np.zeros((self.max_slots, self.pages_per_seq),
                                    np.int32)
@@ -197,6 +201,10 @@ class DecodeScheduler:
              sample),
             lambda: jax.jit(self._make_admit(), donate_argnums=(2,)),
             label="paged_admit")
+
+    def _put(self, x):
+        """Host array -> the block's device(s)."""
+        return jax.device_put(x, self.sharding)
 
     # ------------------------------------------------------------- compiled
     def _make_decode(self):
@@ -324,8 +332,9 @@ class DecodeScheduler:
             bucket = need * self.page_size
             toks = np.zeros((1, bucket), np.int32)
             toks[0, :plen] = sess.context
-            args = (self.params, jnp.asarray(toks), self.pool,
-                    jnp.asarray(pages, jnp.int32), jnp.int32(plen - 1))
+            args = (self.params, self._put(toks), self.pool,
+                    self._put(np.asarray(pages, np.int32)),
+                    self._put(np.int32(plen - 1)))
             if self.sample:
                 self._key, key = jax.random.split(self._key)
                 tok, self.pool = self._admit_fn(*args, key)
@@ -406,8 +415,8 @@ class DecodeScheduler:
                   if self.slots[i] is not None]
         if not active:
             return
-        args = (self.params, jnp.asarray(self.tokens), self.pool,
-                jnp.asarray(self.page_table), jnp.asarray(self.seq_lens))
+        args = (self.params, self._put(self.tokens), self.pool,
+                self._put(self.page_table), self._put(self.seq_lens))
         if self.sample:
             self._key, key = jax.random.split(self._key)
             nxt, self.pool = self._decode(*args, key)
@@ -514,11 +523,11 @@ class DecodeScheduler:
         """Adopt a checkpointed state (cross-geometry resume: leaves arrive
         host-side or default-placed; the pool re-lands wherever the new
         runtime put it)."""
-        self.pool = jax.tree.map(jnp.asarray, tree["pool"])
+        self.pool = jax.tree.map(self._put, tree["pool"])
         self.page_table = np.asarray(tree["page_table"], np.int32).copy()
         self.seq_lens = np.asarray(tree["seq_lens"], np.int32).copy()
         self.tokens = np.asarray(tree["tokens"], np.int32).copy()
-        self.last_tokens_dev = jnp.asarray(self.tokens)
+        self.last_tokens_dev = self._put(self.tokens)
         buf = np.asarray(tree["meta"], np.uint8)
         n = int(np.frombuffer(buf[:8].tobytes(), np.uint64)[0])
         meta = json.loads(buf[8:8 + n].tobytes().decode())

@@ -24,8 +24,31 @@ miss where the preemption tests expect a hit.
 from __future__ import annotations
 
 import dataclasses
+import os
 import threading
 from typing import Any, Callable, Dict, Optional, Tuple
+
+#: the checkout's own persistent compilation cache (listed in .gitignore).
+#: A fixed path: JAX keys cache entries by it, so a moving directory never
+#: hits.
+PERSISTENT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process and
+    return its directory.  Where ``JAX_COMPILATION_CACHE_DIR`` is set JAX
+    already uses it and nothing else is set; otherwise the cache goes to
+    ``PERSISTENT_DIR``.  The entry points (``repro.launch.train``/``serve`` and
+    ``chip_smoke.py``) call this before their first compile; no module
+    calls it on import."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", PERSISTENT_DIR)
+    return PERSISTENT_DIR
 
 
 def freeze(obj) -> Any:

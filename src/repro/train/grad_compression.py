@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 
 
 def quantize(g, *, bits: int = 8):
@@ -78,7 +77,7 @@ def _compressed_allreduce_fn(mesh: Mesh, pod_axis: str, n_leaves: int):
     per-step calls hit the jit cache instead of retracing."""
     spec = tuple(P(pod_axis) for _ in range(n_leaves))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=(spec, spec),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec),
                        out_specs=(spec, spec), axis_names={pod_axis})
     def _run(gs, es):
         red, new = compressed_psum_pod(list(gs), list(es), mesh, pod_axis)
@@ -93,8 +92,8 @@ def compressed_allreduce(grads, err, mesh: Mesh, pod_axis: str = "pod"):
 
     grads/err: pytrees of *global* arrays whose leading dim is sharded over
     ``pod_axis``.  Returns (pod-mean grads, new error-feedback tree) with the
-    same global layout.  The shard-mapped body is jitted because partial-auto
-    shard_map requires a surrounding jit on jax<=0.4.
+    same global layout.  The shard-mapped body is jitted once per (mesh,
+    axis, leaf count) so per-step calls hit the jit cache.
     """
     flat_g, treedef = jax.tree.flatten(grads)
     flat_e = jax.tree.leaves(err)

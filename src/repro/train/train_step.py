@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.models import model as model_lib
 from repro.models.config import ModelConfig, ShapeConfig
 from repro.train import grad_compression as gc
@@ -33,11 +32,18 @@ def abstract_train_state(cfg: ModelConfig, opt_cfg: opt_lib.OptConfig):
 
 
 def _split_micro(batch, n_micro: int):
-    """(G, ...) -> (n_micro, G/n_micro, ...) for every leaf."""
+    """(G, ...) -> (n_micro, G/n_micro, ...) for every leaf.  On a mesh
+    with explicit axes the reshape must name its output sharding: each
+    microbatch keeps the batch dim's sharding, the microbatch dim is
+    unsharded."""
     def split(x):
         g = x.shape[0]
         assert g % n_micro == 0, (g, n_micro)
-        return x.reshape(n_micro, g // n_micro, *x.shape[1:])
+        sh = jax.typeof(x).sharding
+        out = (sh.update(spec=P(None, *sh.spec))
+               if any(a is not None for a in sh.spec) else None)
+        return jax.lax.reshape(x, (n_micro, g // n_micro, *x.shape[1:]),
+                               out_sharding=out)
     return jax.tree.map(split, batch)
 
 
@@ -132,10 +138,10 @@ def make_train_step(cfg: ModelConfig, shape: ShapeConfig,
         n_pods = mesh.shape[pod_axis]
         rep = jax.tree.map(lambda _: P(), params)
         pod_lead = jax.tree.map(lambda _: P(pod_axis), params)
-        run = shard_map(
+        run = jax.shard_map(
             _pod_reduce, mesh=mesh, in_specs=(pod_lead, pod_lead),
             out_specs=(rep, pod_lead), axis_names={pod_axis},
-            check_rep=False)
+            check_vma=False)
         pod_grad = jax.vmap(grad_fn, in_axes=(None, 0))
 
         def split_pod(x):

