@@ -1,0 +1,14 @@
+"""sLSTM blocks: device milliseconds a train step spends in the ``slstm``
+scope (pre-norm, the recurrent scan with its relayout copies, the FFN, the
+residual; forward, recompute and backward).  The step program's leaf ops in
+the traced window, charged to scopes by the program's own compiled HLO
+(``bench/scopes.py``), per whole step run."""
+from bench import scopes
+
+UNIT, LAYER, MOVES, SOURCE = "ms", "sLSTM blocks", "train_tokens_per_s", \
+    "device_trace"
+
+
+def read(run):
+    ms = scopes.layer_ms(run)
+    return None if ms is None else ms["slstm"]

@@ -286,7 +286,8 @@ class ClusterController:
                 rt = SimRuntime(job.step_s, ckpt_every=job.ckpt_every)
             else:
                 devices = self.devices_for(blk.grant.coords)
-                rt = BlockRuntime(blk.grant, job, devices, self.ckpt_root)
+                rt = BlockRuntime(blk.grant, job, devices, self.ckpt_root,
+                                  app_id=app_id)
                 rt.init_state()
                 self._attach_roofline(blk, rt)
             self.runtimes[app_id] = rt

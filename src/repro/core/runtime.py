@@ -30,6 +30,7 @@ from repro.core.inflight import InflightWindow
 from repro.data import pipeline
 from repro.models import model as model_lib
 from repro.models.config import ModelConfig, ShapeConfig
+from repro.obs.programs import PROGRAMS
 from repro.serve import serve_step as serve_lib
 from repro.sharding import ctx as shard_ctx
 from repro.sharding import plans
@@ -83,8 +84,10 @@ class SimJobSpec:
 
 class BlockRuntime(InflightWindow):
     def __init__(self, grant: BlockGrant, job: JobSpec,
-                 devices: Sequence[jax.Device], ckpt_root: str):
+                 devices: Sequence[jax.Device], ckpt_root: str,
+                 app_id: Optional[str] = None):
         self.job = job
+        self.app_id = app_id         # names the train step in obs PROGRAMS
         self.ckpt = CheckpointManager(
             ckpt_root, namespace=job.ckpt_namespace or grant.block_id)
         self.state: Any = None
@@ -140,6 +143,10 @@ class BlockRuntime(InflightWindow):
             batch_abs = pipeline.input_specs(job.cfg, job.shape)
             b_spec = plans.batch_specs(batch_abs, self.mesh, self.axes)
             self.batch_shardings = plans.to_shardings(b_spec, self.mesh)
+            jit_kw = dict(in_shardings=(self.state_shardings,
+                                        self.batch_shardings),
+                          out_shardings=(self.state_shardings, None),
+                          donate_argnums=(0,))
 
             def build_train():
                 # everything the closure captures (ctx, shardings) is a
@@ -147,21 +154,21 @@ class BlockRuntime(InflightWindow):
                 # same key can adopt this wrapper — and jax's own jit
                 # cache makes re-attach on the same chips recompile-free
                 step = train_lib.make_train_step(job.cfg, job.shape, job.opt)
-                ctx, st_sh, b_sh = (self.ctx, self.state_shardings,
-                                    self.batch_shardings)
+                ctx = self.ctx
 
                 def fn(state, batch):
                     with shard_ctx.use(ctx):
                         return step(state, batch)
 
-                return jax.jit(fn, in_shardings=(st_sh, b_sh),
-                               out_shardings=(st_sh, None),
-                               donate_argnums=(0,))
+                return jax.jit(fn, **jit_kw)
 
             self._step = self._cached(
                 self._cache_key("train_step", compile_cache.freeze(job.opt),
                                 ("donate", 0)),
                 build_train, "train_step")
+            if self.app_id is not None:
+                PROGRAMS.register(self.app_id, self._step.__wrapped__,
+                                  (state_abs, batch_abs), **jit_kw)
             self.data = pipeline.DataIterator(job.cfg, job.shape,
                                               seed=job.seed,
                                               shardings=self.batch_shardings)
@@ -429,6 +436,8 @@ class BlockRuntime(InflightWindow):
         self.cache = None
         self.sessions = None
         self.token = None
+        if self.app_id is not None:
+            PROGRAMS.forget(self.app_id)
 
     @property
     def progress_lost(self) -> int:
@@ -531,7 +540,7 @@ class BlockRuntime(InflightWindow):
         """Failure migration / elastic resize: new runtime on new devices,
         state restored from the old block's checkpoints (resharded onto the
         new mesh by the checkpoint manager)."""
-        rt = cls(grant, old.job, devices, ckpt_root)
+        rt = cls(grant, old.job, devices, ckpt_root, app_id=old.app_id)
         rt.init_state()
         old.ckpt.wait()
         if old.ckpt.latest_step() is not None:

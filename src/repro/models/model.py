@@ -51,6 +51,7 @@ def count_active_params(cfg: ModelConfig) -> int:
 # losses
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("lm_head")
 def _xent(logits, labels, mask):
     """Cross-entropy in fp32 with a validity mask.  logits: (B,S,V)."""
     lf = logits.astype(jnp.float32)

@@ -213,13 +213,17 @@ def group_fwd(gp, x, cfg: ModelConfig, *, positions, cache, cache_len, extra,
                                       state=st)
             return x + y, new_st
         m_states = None if cache is None else cache["mlstm"]
-        x, new_m = _scan_sublayers(m_step, x, gp["mlstm"], m_states,
-                                   cfg.xlstm.slstm_every - 1)
-        h = apply_norm(gp["slstm"]["ln"], x, cfg.norm)
+        # the scope holds the sublayer scan itself, so its loop (and the
+        # backward's) is charged to the mLSTM blocks too
+        with jax.named_scope("mlstm"):
+            x, new_m = _scan_sublayers(m_step, x, gp["mlstm"], m_states,
+                                       cfg.xlstm.slstm_every - 1)
         s_state = None if cache is None else cache["slstm"]
-        y, new_s = ssm.slstm_fwd(gp["slstm"]["blk"], h, cfg.xlstm,
-                                 cfg.d_model, state=s_state)
-        x = x + y
+        with jax.named_scope("slstm"):
+            h = apply_norm(gp["slstm"]["ln"], x, cfg.norm)
+            y, new_s = ssm.slstm_fwd(gp["slstm"]["blk"], h, cfg.xlstm,
+                                     cfg.d_model, state=s_state)
+            x = x + y
         nc = None if cache is None else {"mlstm": new_m, "slstm": new_s}
         return x, aux, nc
     if fam == "hybrid":
@@ -409,7 +413,8 @@ def embed_inputs(params, cfg: ModelConfig, batch: Dict[str, Any]):
             x = jnp.where(batch["mask"][..., None],
                           params["mask_embed"][None, None], x)
         return x
-    tok = params["embed"][batch["tokens"]]
+    with jax.named_scope("lm_head"):
+        tok = params["embed"][batch["tokens"]]
     if cfg.frontend == "patch" and "patches" in batch:
         patches = batch["patches"].astype(jnp.bfloat16) @ params["patch_proj"]
         tok = jnp.concatenate([patches, tok], axis=1)
@@ -445,10 +450,13 @@ def forward(params, cfg: ModelConfig, x, *, positions, cache=None,
 
     xs = params["layers"] if cache is None else (params["layers"], cache)
     (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
-    x = apply_norm(params["final_norm"], x, cfg.norm)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = x @ head
-    if cfg.logits_softcap > 0:
-        logits = jnp.tanh(logits / cfg.logits_softcap) * cfg.logits_softcap
-    logits = shard_ctx.constrain_logits(logits)
+    with jax.named_scope("lm_head"):
+        x = apply_norm(params["final_norm"], x, cfg.norm)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = x @ head
+        if cfg.logits_softcap > 0:
+            logits = (jnp.tanh(logits / cfg.logits_softcap)
+                      * cfg.logits_softcap)
+        logits = shard_ctx.constrain_logits(logits)
     return logits, aux, (None if cache is None else new_cache)

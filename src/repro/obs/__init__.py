@@ -1,7 +1,8 @@
-"""Observability layer: distributed tracing, time-series metrics, and
-flight-recorder postmortems for the whole control plane.
+"""Observability layer: distributed tracing, time-series metrics,
+flight-recorder postmortems for the whole control plane, and the train
+step's device ops by model layer.
 
-Three stdlib-only, inert-by-default components:
+Three stdlib-only, inert-by-default components, and the program map:
 
 * ``trace.TRACER`` — a process-global tracer that opens spans with
   trace/span/parent ids and propagates a trace context along each
@@ -23,6 +24,10 @@ Three stdlib-only, inert-by-default components:
   dumps a postmortem JSON artifact automatically on block FAILED, pod
   death, or a daemon pump crash, downloadable via the gateway
   (``GET /v1/postmortems``).
+
+* ``programs.PROGRAMS`` — the train steps the runtimes registered, and,
+  asked for when a device profile is read, each step's map from HLO
+  instruction to model layer scope (``repro.models.LAYER_SCOPES``).
 
 The ``Monitor`` remains the semantic accountant (EWMAs, SLO outcomes,
 federation totals); it is now one consumer of the event stream among

@@ -31,12 +31,21 @@ Design constraints (why this is not a straight OpenTelemetry clone):
 Spans are kept in a bounded ring and exported as Chrome-trace JSON
 (``{"traceEvents": [...]}``, ``ph: "X"`` complete events) which loads in
 ``chrome://tracing`` and Perfetto.
+
+* **On the device trace's clock.**  While the tracer is enabled and JAX is
+  imported, each span opened with ``span()`` also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name on its thread, so a
+  profile taken meanwhile shows the host spans in the same timeline as the
+  device ops.  ``record()``-ed spans (``daemon.queue:*``) have already
+  elapsed when they are recorded, and stay on the host clock only.  JAX is
+  never imported from here: a process without it records spans alone.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import itertools
+import sys
 import threading
 import time
 from typing import Deque, Dict, List, Optional, Tuple
@@ -100,13 +109,15 @@ _NOOP = _NoopSpan()
 
 
 class _LiveSpan:
-    """An open span: closes (and lands in the tracer ring) on __exit__."""
+    """An open span: closes (and lands in the tracer ring) on __exit__,
+    ending its profiler annotation (if any) first."""
 
-    __slots__ = ("tracer", "span")
+    __slots__ = ("tracer", "span", "annotation")
 
-    def __init__(self, tracer: "Tracer", span: Span):
+    def __init__(self, tracer: "Tracer", span: Span, annotation=None):
         self.tracer = tracer
         self.span = span
+        self.annotation = annotation
 
     def __enter__(self):
         return self
@@ -114,6 +125,8 @@ class _LiveSpan:
     def __exit__(self, exc_type, exc, tb):
         if exc_type is not None:
             self.span.args.setdefault("error", repr(exc))
+        if self.annotation is not None:
+            self.annotation.__exit__(None, None, None)
         self.tracer._close(self.span)
         return False
 
@@ -225,7 +238,12 @@ class Tracer:
             with self._lock:
                 self._blocks.setdefault(app_id, (trace_id, span.span_id))
         st.append(span)
-        return _LiveSpan(self, span)
+        profiler = sys.modules.get("jax.profiler")
+        annotation = None
+        if profiler is not None:
+            annotation = profiler.TraceAnnotation(name)
+            annotation.__enter__()
+        return _LiveSpan(self, span, annotation)
 
     def record(self, name: str, t0: float, t1: float, cat: str = "span",
                ctx: Optional[Context] = None, app_id: Optional[str] = None,

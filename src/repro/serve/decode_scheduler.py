@@ -329,18 +329,20 @@ class DecodeScheduler:
             self.queued.popleft()
             slot = free_slots[0]
 
-            bucket = need * self.page_size
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :plen] = sess.context
-            args = (self.params, self._put(toks), self.pool,
-                    self._put(np.asarray(pages, np.int32)),
-                    self._put(np.int32(plen - 1)))
-            if self.sample:
-                self._key, key = jax.random.split(self._key)
-                tok, self.pool = self._admit_fn(*args, key)
-            else:
-                tok, self.pool = self._admit_fn(*args)
-            first = int(tok)
+            with TRACER.span("serve.prefill", cat="serve", session=sess.sid,
+                             prompt_tokens=plen, pages=len(pages)):
+                bucket = need * self.page_size
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :plen] = sess.context
+                args = (self.params, self._put(toks), self.pool,
+                        self._put(np.asarray(pages, np.int32)),
+                        self._put(np.int32(plen - 1)))
+                if self.sample:
+                    self._key, key = jax.random.split(self._key)
+                    tok, self.pool = self._admit_fn(*args, key)
+                else:
+                    tok, self.pool = self._admit_fn(*args)
+                first = int(tok)
 
             sess.state = "running"
             sess.slot = slot

@@ -176,12 +176,14 @@ def make_train_step(cfg: ModelConfig, shape: ShapeConfig,
                 grads, loss = _accum_overlapped(params, micro)
             else:
                 grads, loss = _accum_serial(params, micro)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32) / n_micro,
-                                 grads)
+            with jax.named_scope("optimizer"):
+                grads = jax.tree.map(
+                    lambda g: g.astype(jnp.float32) / n_micro, grads)
             loss = loss / n_micro
             metrics = {}
-        new_params, new_opt, opt_metrics = opt_lib.apply(
-            opt_cfg, params, state["opt"], grads)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, opt_metrics = opt_lib.apply(
+                opt_cfg, params, state["opt"], grads)
         out_metrics = {"loss": loss, **opt_metrics}
         return {"params": new_params, "opt": new_opt}, out_metrics
 

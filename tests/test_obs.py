@@ -2,8 +2,12 @@
 scrape format, flight-recorder postmortems on pod death, trace-context
 survival across preempt/resume, and the gateway's /metrics, /v1/trace,
 X-Request-ID and 429/413 surfaces."""
+import glob
 import json
+import os
 import re
+import subprocess
+import sys
 import time
 import urllib.error
 import urllib.request
@@ -456,3 +460,98 @@ def test_straggler_surfaces_in_status_and_report(tmp_path):
     assert blk_id in obs["stragglers"]
     assert REGISTRY.gauge_value("repro_stragglers") == len(
         obs["stragglers"])
+
+
+# ============================================ spans beside the device ops
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def test_prefill_span_nests_in_its_decode_round():
+    """``serve.prefill`` surrounds one admission's prefill (bucket, admit
+    program, first-token sync) inside ``serve.decode_round``, with the
+    session, prompt length and pages as args; off, nothing is recorded."""
+    from repro.models import model as model_lib
+    from repro.models.config import AttentionConfig, ModelConfig
+    from repro.serve.decode_scheduler import DecodeScheduler
+    cfg = ModelConfig(name="obs_t", family="dense", n_layers=1, d_model=32,
+                      vocab_size=64, d_ff=64,
+                      attention=AttentionConfig(n_heads=4, n_kv_heads=2,
+                                                head_dim=8),
+                      param_dtype="float32")
+    params = model_lib.init_params(cfg, jax.random.PRNGKey(0))
+    sch = DecodeScheduler(cfg, params, page_size=4, n_pages=0, max_slots=2,
+                          max_seq_len=32)
+    sch.submit([3, 1, 4, 1, 5], max_new_tokens=3)
+    sch.step()                                  # tracer off
+    assert TRACER.spans() == []
+    sid = sch.submit([2, 7], max_new_tokens=3)
+    TRACER.enable()
+    sch.step()
+    spans = TRACER.spans()
+    (pre,) = [s for s in spans if s.name == "serve.prefill"]
+    (rnd,) = [s for s in spans if s.name == "serve.decode_round"]
+    assert pre.parent_id == rnd.span_id and pre.trace_id == rnd.trace_id
+    assert rnd.t0 <= pre.t0 <= pre.t1 <= rnd.t1
+    assert pre.args == {"session": sid, "prompt_tokens": 2, "pages": 1}
+
+
+def test_live_spans_are_annotations_on_the_device_trace_clock(tmp_path):
+    """A profile taken while tracing holds every live span as a host
+    annotation of the same name, and the benchmark's window map
+    (``bench.trace.clock_map``) puts each span's ends within 100 us of its
+    annotation's; a recorded span has no annotation."""
+    if ROOT not in sys.path:
+        sys.path.insert(0, ROOT)
+    from bench import trace as bench_trace
+    from jax.profiler import ProfileData
+    TRACER.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation(bench_trace.WINDOW):
+            h0 = time.perf_counter()
+            with TRACER.span("obs.outer"):
+                time.sleep(0.02)
+                with TRACER.span("obs.inner"):
+                    time.sleep(0.01)
+                    with TRACER.span("obs.innermost"):
+                        time.sleep(0.005)
+                time.sleep(0.01)
+            TRACER.record("obs.recorded", h0, h0 + 0.001)
+            time.sleep(0.005)
+            h1 = time.perf_counter()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    events.setdefault(ev.name, []).append(
+                        (float(ev.start_ns),
+                         float(ev.start_ns + ev.duration_ns)))
+    (window,) = events[bench_trace.WINDOW]
+    to_trace = bench_trace.clock_map(window, (h0, h1))
+    spans = {s.name: s for s in TRACER.spans()}
+    for name in ("obs.outer", "obs.inner", "obs.innermost"):
+        (a0, a1), = events[name]
+        assert abs(to_trace(spans[name].t0) - a0) < 100e3, name
+        assert abs(to_trace(spans[name].t1) - a1) < 100e3, name
+    assert "obs.recorded" in spans and "obs.recorded" not in events
+
+
+def test_tracer_runs_without_jax():
+    """``repro.obs`` (programs included) imports and traces in a process
+    where JAX cannot be imported."""
+    code = ("import sys; sys.modules['jax'] = None\n"
+            "import repro.obs, repro.obs.programs\n"
+            "from repro.obs import TRACER\n"
+            "TRACER.enable()\n"
+            "with TRACER.span('a'):\n"
+            "    pass\n"
+            "assert [s.name for s in TRACER.spans()] == ['a']\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(ROOT, "src"))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=60)
+    assert out.returncode == 0, out.stderr
