@@ -10,6 +10,8 @@ passing a state runs from it and returns the updated one (decode passes S=1).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -184,11 +186,25 @@ def slstm_init(key, d_model: int, cfg: XLSTMConfig, dtype):
     }
 
 
+#: time steps per trip of the sLSTM loop: the bf16 sublane tile, so each
+#: trip's (B, T, 4*d_model) gate slab and (B, T, d_model) output slab are
+#: whole tiles.  A shorter sequence, or the last S mod T steps, run one
+#: step per trip.
+SLSTM_CHUNK = 16
+
+
 def slstm_fwd(p, x, cfg: XLSTMConfig, d_model: int, *, state=None):
+    """The recurrence runs S // SLSTM_CHUNK trips of SLSTM_CHUNK unrolled
+    steps, then the S mod SLSTM_CHUNK steps left one per trip.  The chunked
+    gate input is the scan's ``xs``, batch-major within a trip, so the
+    forward reads and the backward writes it one aligned chunk per trip
+    (as a loop constant its cotangent would be a sequence-sized buffer
+    carried through every trip)."""
     B, S, _ = x.shape
     H = cfg.n_heads
     Dh = d_model // H
-    gates_x = (x @ p["w_gates"]).reshape(B, S, 4, H, Dh)
+    T = SLSTM_CHUNK
+    n_chunks, S_c = S // T, S // T * T
 
     if state is None:
         h0 = jnp.zeros((B, H, Dh), jnp.float32)
@@ -220,9 +236,30 @@ def slstm_fwd(p, x, cfg: XLSTMConfig, d_model: int, *, state=None):
         # full-buffer converts per trip) and halves the stacked-output HBM
         return (h, c, n, m_new), h.astype(jnp.bfloat16)
 
-    (hf, cf, nf, mf), hs = jax.lax.scan(step, (h0, c0, n0, m0),
-                                        jnp.moveaxis(gates_x, 1, 0))
-    y = jnp.moveaxis(hs, 0, 1).reshape(B, S, d_model).astype(x.dtype)
+    # checkpointed: the backward keeps one incoming carry per trip and
+    # recomputes the trip's steps, where saving each step's intermediates
+    # would stack every one of them on its own and slice each per trip
+    @functools.partial(jax.checkpoint, prevent_cse=False)
+    def chunk(carry, gx):                           # gx: (B, T, 4*d_model)
+        hs = []
+        for t in range(T):
+            carry, h = step(carry, gx[:, t].reshape(B, 4, H, Dh))
+            hs.append(h.reshape(B, d_model))
+        return carry, jnp.stack(hs, axis=1)        # (B, T, d_model)
+
+    carry, ys = (h0, c0, n0, m0), []
+    if n_chunks:
+        # (B, S_c, d) -> (n_chunks, B, T, d) before the projection: moving
+        # x's whole tiles is a quarter of moving its product's
+        xc = x[:, :S_c].reshape(B, n_chunks, T, d_model).swapaxes(0, 1)
+        carry, hs = jax.lax.scan(chunk, carry, xc @ p["w_gates"])
+        ys.append(hs.swapaxes(0, 1).reshape(B, S_c, d_model))
+    if S > S_c:
+        gates_x = (x[:, S_c:] @ p["w_gates"]).reshape(B, S - S_c, 4, H, Dh)
+        carry, hs = jax.lax.scan(step, carry, jnp.moveaxis(gates_x, 1, 0))
+        ys.append(jnp.moveaxis(hs, 0, 1).reshape(B, S - S_c, d_model))
+    hf, cf, nf, mf = carry
+    y = jnp.concatenate(ys, axis=1).astype(x.dtype)
     y = ops.rmsnorm(y, p["out_norm"])
     ff = jax.nn.silu(y @ p["w_ff_gate"]) * (y @ p["w_ff_up"])
     out = ff @ p["w_ff_down"]

@@ -8,8 +8,10 @@ import numpy as np
 import pytest
 
 import repro.configs as C
+from repro.configs import xlstm_350m
 from repro.data import pipeline
-from repro.models import model
+from repro.kernels import ops
+from repro.models import model, ssm
 from repro.models.config import ShapeConfig
 
 KEY = jax.random.PRNGKey(7)
@@ -147,3 +149,130 @@ def test_param_counts_match_published():
     for arch, want_b in expected.items():
         n = model.count_params(model.abstract_params(C.get(arch))) / 1e9
         assert abs(n - want_b) / want_b < 0.4, (arch, n, want_b)
+
+
+# ------------------------------------------------- sLSTM chunked recurrence
+
+def _slstm_per_step(p, x, cfg, d_model, state=None):
+    """The sLSTM recurrence one time step per scan trip, over the whole
+    sequence: the reference for ``ssm.slstm_fwd``'s chunked loop."""
+    B, S, _ = x.shape
+    H = cfg.n_heads
+    Dh = d_model // H
+    gates_x = (x @ p["w_gates"]).reshape(B, S, 4, H, Dh)
+    if state is None:
+        z = jnp.zeros((B, H, Dh), jnp.float32)
+        state = {"slstm": (z, z, jnp.ones((B, H, Dh), jnp.float32), z)}
+    r = p["r_gates"].astype(jnp.float32)
+
+    def step(carry, gx):
+        h, c, n, m = carry
+        rec = jnp.einsum("bhd,hdg->bhg", h, r).reshape(B, H, 4, Dh)
+        g = gx.astype(jnp.float32) + jnp.moveaxis(rec, 2, 1)
+        z_t = jnp.tanh(g[:, 0])
+        i_t = g[:, 1]
+        f_t = g[:, 2]
+        o_t = jax.nn.sigmoid(g[:, 3])
+        logf = jax.nn.log_sigmoid(f_t)
+        m_new = jnp.maximum(logf + m, i_t)
+        i_p = jnp.exp(i_t - m_new)
+        f_p = jnp.exp(logf + m - m_new)
+        c = f_p * c + i_p * z_t
+        n = f_p * n + i_p
+        h = o_t * c / jnp.maximum(jnp.abs(n), 1.0)
+        return (h, c, n, m_new), h.astype(jnp.bfloat16)
+
+    final, hs = jax.lax.scan(step, tuple(state["slstm"]),
+                             jnp.moveaxis(gates_x, 1, 0))
+    y = jnp.moveaxis(hs, 0, 1).reshape(B, S, d_model).astype(x.dtype)
+    y = ops.rmsnorm(y, p["out_norm"])
+    ff = jax.nn.silu(y @ p["w_ff_gate"]) * (y @ p["w_ff_up"])
+    return ff @ p["w_ff_down"], {"slstm": final}
+
+
+def _slstm_case(S, with_state):
+    cfg = xlstm_350m.smoke_config()
+    d = cfg.d_model
+    H, Dh = cfg.xlstm.n_heads, d // cfg.xlstm.n_heads
+    ks = jax.random.split(jax.random.PRNGKey(S * 2 + with_state), 8)
+    p = ssm.slstm_init(ks[0], d, cfg.xlstm, jnp.float32)
+    x = jax.random.normal(ks[1], (2, S, d), jnp.float32)
+    state = None
+    if with_state:
+        sh = (2, H, Dh)
+        state = {"slstm": (jax.random.normal(ks[2], sh) * 0.5,
+                           jax.random.normal(ks[3], sh),
+                           1.0 + jax.random.uniform(ks[4], sh),
+                           jax.random.normal(ks[5], sh))}
+    ct = jax.random.normal(ks[6], (2, S, d), jnp.float32)
+    return cfg, p, x, state, ct
+
+
+@pytest.mark.parametrize("with_state", [False, True],
+                         ids=["zero_state", "with_state"])
+@pytest.mark.parametrize("S", [1, 15, 16, 37, 64])
+def test_slstm_chunked_matches_per_step(S, with_state):
+    """Outputs, final (h, c, n, m) and the gradients with respect to x,
+    w_gates and r_gates match the one-step-per-trip recurrence."""
+    cfg, p, x, state, ct = _slstm_case(S, with_state)
+    d = cfg.d_model
+
+    def run(fwd):
+        def loss(p, x):
+            y, st = fwd(p, x, cfg.xlstm, d, state=state)
+            return (jnp.sum(y * ct)
+                    + sum(jnp.sum(s * (i + 1)) for i, s in
+                          enumerate(st["slstm"]))), (y, st["slstm"])
+        (_, (y, st)), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1), has_aux=True))(p, x)
+        return y, st, g
+
+    y, st, (gp, gx) = run(ssm.slstm_fwd)
+    y_ref, st_ref, (gp_ref, gx_ref) = run(_slstm_per_step)
+    tol = dict(rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(y, y_ref, **tol)
+    for a, b in zip(st, st_ref):
+        np.testing.assert_allclose(a, b, **tol)
+    np.testing.assert_allclose(gx, gx_ref, **tol)
+    for k in ("w_gates", "r_gates"):
+        np.testing.assert_allclose(gp[k], gp_ref[k], **tol)
+
+
+def _scan_lengths(fn, *args):
+    """The lengths of the scans in ``fn``'s jaxpr, outermost first, each
+    with the lengths of the scans nested in its body."""
+    def walk(jaxpr):
+        out = []
+        for e in jaxpr.eqns:
+            if e.primitive.name == "scan":
+                out.append((e.params["length"],
+                            walk(e.params["jaxpr"].jaxpr)))
+            else:
+                for sub in jax.core.jaxprs_in_params(e.params):
+                    out += walk(sub)
+        return out
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr)
+
+
+@pytest.mark.parametrize("S,want", [
+    (2048, [(128, [])]),            # 128 chunks of 16, no per-step loop
+    (37, [(2, []), (5, [])]),       # 2 chunks, then a 5-step tail
+    (15, [(15, [])]),               # a short prefill: one step a trip
+    (1, [(1, [])]),                 # decode: the single step
+])
+def test_slstm_loop_trips_follow_the_sequence_length(S, want):
+    """At the benchmark's widths the sLSTM forward loops S // 16 chunked
+    trips with no loop inside them, then S mod 16 single steps."""
+    cfg = xlstm_350m.CONFIG
+    d = cfg.d_model
+    p = jax.eval_shape(lambda: ssm.slstm_init(jax.random.PRNGKey(0), d,
+                                              cfg.xlstm, jnp.bfloat16))
+    x = jax.ShapeDtypeStruct((2, S, d), jnp.bfloat16)
+    state = (None if S > 1 else jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(*s),
+        ssm.slstm_state_spec(cfg.xlstm, d, 2),
+        is_leaf=lambda s: isinstance(s, tuple) and isinstance(s[1], type)))
+    got = _scan_lengths(
+        lambda p, x, st: ssm.slstm_fwd(p, x, cfg.xlstm, d, state=st),
+        p, x, state)
+    assert got == want
