@@ -12,14 +12,16 @@ control (the reference in float8, put in the program's place) against the
 reference, and for a train cell the fault of half the batch left out, the
 mean taken over the rest, and a witness: the reference with its matrix
 products' operands in bfloat16, the configuration's own precision.  ``--rates`` instead offers the cell's serve mix
-at each rate in turn to one serve block and reports the tails and the
-tokens completed per second, to find the knee."""
+at each rate in turn to one serve block and reports the tails, the
+tokens completed per second and the KV pages free in the window, to find
+the knee and the share of the pool that the mix fills."""
 from __future__ import annotations
 
 import argparse
 import gc
 import json
 import os
+import statistics
 import sys
 import time
 
@@ -27,6 +29,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 from bench import check, load, run, system      # noqa: E402
+
+
+#: the tails a sweep reports at each rate
+SWEEP_TAILS = ("ttft_p50_s", "ttft_p80_s", "ttft_p90_s", "itl_p50_s",
+               "itl_p95_s")
 
 
 def _ints(s):
@@ -81,9 +88,11 @@ def train_readings(daemon, blk, seeds, control_seeds, out):
                 print(json.dumps(rec), flush=True)
 
 
-def serve_window(daemon, gateway, blk, seed, seconds, rate=None):
+def serve_window(daemon, gateway, blk, seed, seconds, rate=None,
+                 watch=None):
     """One window of the cell's serve mix (at ``rate`` if given) on the
-    block; returns the Load once the window's requests have ended."""
+    block; returns the Load once the window's requests have ended.
+    ``watch``, if given, is called every quarter second of the window."""
     mix = dict(blk.mix)
     if rate is not None:
         mix["rate_per_s"] = rate
@@ -92,7 +101,10 @@ def serve_window(daemon, gateway, blk, seed, seconds, rate=None):
     ld = run.Load(gateway.port, blk.app, reqs)
     w0 = time.perf_counter()
     ld.start(w0)
-    time.sleep(seconds)
+    while (left := w0 + seconds - time.perf_counter()) > 0:
+        if watch is not None:
+            watch()
+        time.sleep(min(0.25, left))
     ld.wait_measured(w0 + seconds + run.DRAIN_S)
     ld.close()
     return ld, w0
@@ -117,6 +129,7 @@ def serve_readings(daemon, gateway, blk, seeds, control_seeds, seconds, out):
                    "failed": sm["failed"], "n": sm["n"],
                    "program_s": t1 - t0,
                    "reference_s": time.perf_counter() - t1}
+            rec["correct"] = _judged(blk, check.serve_numbers(gaps))
             if seed in seeds:
                 out.append(rec)
                 print(json.dumps(rec), flush=True)
@@ -125,7 +138,8 @@ def serve_readings(daemon, gateway, blk, seeds, control_seeds, seconds, out):
                 ctl = check.serve_reference(blk.cfg, seed, sample, "fp8")
                 rec = {"seed": seed, "who": "control_fp8",
                        **check.serve_numbers(ctl), "tokens": len(ctl),
-                       "seconds": time.perf_counter() - t2}
+                       "seconds": time.perf_counter() - t2,
+                       "correct": _judged(blk, check.serve_numbers(ctl))}
                 out.append(rec)
                 print(json.dumps(rec), flush=True)
 
@@ -135,12 +149,19 @@ def sweep(daemon, gateway, blk, rates, seconds, out):
     rt = daemon.runtime(blk.app)
     for i, rate in enumerate(rates):
         before = dict(rt.sessions.stats())
-        ld, w0 = serve_window(daemon, gateway, blk, 1000 + i, seconds, rate)
-        sm = run.serve_metrics(ld)
+        free = []
+        ld, w0 = serve_window(
+            daemon, gateway, blk, 1000 + i, seconds, rate,
+            watch=lambda: free.append(rt.sessions.stats()["free_pages"]))
+        sm = run.serve_metrics(ld, SWEEP_TAILS)
         toks = sum(1 for s in ld.sent for t in s.times
                    if w0 <= t <= w0 + seconds)
         rec = {"rate_per_s": rate, **sm,
                "tokens_per_s_in_window": toks / seconds,
+               # the KV pool's free pages, read every quarter second of
+               # the window: the pool in use is the total less these
+               "free_pages_min": min(free),
+               "free_pages_median": statistics.median(free),
                "unfinished": sum(1 for s in ld.measured if not s.done),
                "scheduler_before": before,
                "scheduler_after": dict(rt.sessions.stats())}

@@ -112,11 +112,12 @@ def train_reference(cfg, mix, seed: int, prec: str = "f32", steps: int = 3,
     where ``rows`` is set: a fault the comparison has to catch)."""
     import jax
     import jax.numpy as jnp
-    from bench.reference import xlstm
-    from bench import data
+    from bench import data, reference
+    from bench.reference import common
+    model = reference.of(cfg)
     o = cfg["optimizer"]
     with jax.default_matmul_precision("highest"):
-        params = xlstm.init_params(cfg, seed)
+        params = model.init_params(cfg, seed)
         p0 = params
         m = jax.tree.map(jnp.zeros_like, params)
         v = jax.tree.map(jnp.zeros_like, params)
@@ -127,11 +128,11 @@ def train_reference(cfg, mix, seed: int, prec: str = "f32", steps: int = 3,
                                           mix["seq_len"], cfg["vocab_size"])
             if rows:
                 toks, labels = toks[:rows], labels[:rows]
-            loss, grads = xlstm.loss_and_grad(params, jnp.asarray(toks),
+            loss, grads = model.loss_and_grad(params, jnp.asarray(toks),
                                               jnp.asarray(labels), cfg, prec)
-            params, m, v, gnorm, clipped = xlstm.adamw(
+            params, m, v, gnorm, clipped = common.adamw(
                 params, grads, m, v, float(step + 1),
-                float(xlstm.lr_at(o, step + 1)), b1=o["b1"], b2=o["b2"],
+                float(common.lr_at(o, step + 1)), b1=o["b1"], b2=o["b2"],
                 eps=o["eps"], wd=o["weight_decay"], clip=o["clip_norm"])
             losses.append(float(loss))
             norms.append(float(gnorm))
@@ -169,28 +170,41 @@ def diff_norms(a, b):
     return [float(x) for x in f(a, b)]
 
 
+#: the serve reference pads its positions and compared tokens up to these
+#: steps, and every batch to its full rows, so that runs on other seeds
+#: find its few programs in the compilation cache; under causal attention
+#: no padding reaches a compared position
+PAD_POSITIONS, PAD_TOKENS = 256, 64
+
+
+def _pad(n: int, step: int) -> int:
+    return -(-n // step) * step
+
+
 def serve_reference(cfg, seed: int, samples, prec: str = "f32",
                     batch: int = 4):
     """Per sampled request (prompt, served tokens): the reference's gap of
     each served token; with ``prec`` below float32 (the control), the gap
     under the float32 reference of the token the control puts first."""
     import jax
-    from bench.reference import llama
+    from bench import reference
+    model = reference.of(cfg)
     gaps = []
     with jax.default_matmul_precision("highest"):
         for i in range(0, len(samples), batch):
             part = samples[i:i + batch]
-            T = max(len(p) + len(s) - 1 for p, s in part)
-            N = max(len(s) for _, s in part)
-            toks = np.zeros((len(part), T), np.int32)
-            idx = np.zeros((len(part), N), np.int32)
+            T = _pad(max(len(p) + len(s) - 1 for p, s in part),
+                     PAD_POSITIONS)
+            N = _pad(max(len(s) for _, s in part), PAD_TOKENS)
+            toks = np.zeros((batch, T), np.int32)
+            idx = np.zeros((batch, N), np.int32)
             for b, (p, s) in enumerate(part):
                 seq = list(p) + list(s[:-1])
                 toks[b, :len(seq)] = seq
                 idx[b, :len(s)] = np.arange(len(p) - 1, len(p) - 1 + len(s))
-            ref = np.asarray(llama.logits_at(cfg, seed, toks, idx, "f32"))
+            ref = np.asarray(model.logits_at(cfg, seed, toks, idx, "f32"))
             if prec != "f32":
-                ctl = np.asarray(llama.logits_at(cfg, seed, toks, idx, prec))
+                ctl = np.asarray(model.logits_at(cfg, seed, toks, idx, prec))
             for b, (p, s) in enumerate(part):
                 r = ref[b, :len(s)]
                 pick = (np.asarray(s) if prec == "f32"

@@ -1,11 +1,13 @@
 """The one traffic generator: reads a mix's data file and makes the work a
 run offers, from the seed.
 
-Serve mixes are open-loop.  Every seed gets the same set of prompt lengths,
-output lengths and inter-arrival gaps (the quantiles of the mix's
-distributions on a fixed grid), in an order and with prompt token ids that
-the seed draws, so that seeds change which request is which and not how
-much work the window holds."""
+Serve mixes are open-loop.  Every seed gets the same requests at the same
+times: the prompt lengths, output lengths and inter-arrival gaps are the
+quantiles of the mix's distributions on a fixed grid, put in one order that
+the mix's ``order_seed`` draws.  The seed draws only the prompts' token ids.
+An order of the seed's own would change how the arrivals bunch, and with
+them the queue for the block's slots and the TTFT tail: a run would then
+measure the seed and not the system."""
 from __future__ import annotations
 
 import dataclasses
@@ -38,15 +40,16 @@ def _gaps(n: int, rate: float) -> np.ndarray:
                      for i in range(n)])
 
 
-def _segment(rng, mix: Dict, vocab: int, n: int, t0: float, span: float,
-             idx0: int, measured: bool) -> List[Request]:
+def _segment(rng, order, mix: Dict, vocab: int, n: int, t0: float,
+             span: float, idx0: int, measured: bool) -> List[Request]:
     """n requests due in [t0, t0 + span): the gaps, scaled so that they
-    fill the span exactly, at the mix's mean rate n / span."""
+    fill the span exactly, at the mix's mean rate n / span.  ``order``
+    puts the lengths and gaps in order, ``rng`` draws the token ids."""
     if n <= 0:
         return []
-    plens = rng.permutation(_quantiles(n, mix["prompt_tokens"]))
-    olens = rng.permutation(_quantiles(n, mix["output_tokens"]))
-    gaps = rng.permutation(_gaps(n, mix["rate_per_s"]))
+    plens = order.permutation(_quantiles(n, mix["prompt_tokens"]))
+    olens = order.permutation(_quantiles(n, mix["output_tokens"]))
+    gaps = order.permutation(_gaps(n, mix["rate_per_s"]))
     due = t0 + np.concatenate([[0.0], np.cumsum(gaps)[:-1]]) * (
         span / gaps.sum())
     out = []
@@ -63,11 +66,13 @@ def serve_requests(mix: Dict, vocab: int, seed: int, seconds: float,
     ``tail_s`` more of the same mix that keep the load on while the
     window's last requests finish."""
     rng = np.random.default_rng(seed)
+    order = np.random.default_rng(mix["order_seed"])
     rate = mix["rate_per_s"]
     n_w = max(1, int(round(rate * seconds)))
-    win = _segment(rng, mix, vocab, n_w, 0.0, seconds, 0, True)
+    win = _segment(rng, order, mix, vocab, n_w, 0.0, seconds, 0, True)
     n_t = int(round(rate * tail_s))
-    tail = _segment(rng, mix, vocab, n_t, seconds, tail_s, n_w, False)
+    tail = _segment(rng, order, mix, vocab, n_t, seconds, tail_s, n_w,
+                    False)
     return win + tail
 
 

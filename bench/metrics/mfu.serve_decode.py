@@ -1,12 +1,13 @@
 """Model step: FLOPs of the decode rounds (every weight once per decoded
-token and attention over its cache; ``bench/flops.py``) over the device
-time of the decode program times the chip's bf16 peak.  Decoded tokens are
-those the clients received in the traced window after each request's first; a
-decode program run is a serve-chip program run in the traced window that holds a
+token and attention over its cache; the count of the block's family,
+``bench/families/``) over the device time of the decode program times the
+chip's bf16 peak.  Decoded tokens are those the clients received in the
+traced window after each request's first; a decode program run is a
+serve-chip program run in the traced window that holds a
 ``paged_attention`` kernel."""
 import numpy as np
 
-from bench import flops as F
+from bench import families
 
 UNIT, LAYER, MOVES, SOURCE = "%", "model step", "itl_p95_s", "device_trace"
 
@@ -49,6 +50,6 @@ def read(run):
     toks = decoded_tokens(run)
     if not runs or not toks:
         return None
-    flops = sum(F.llama_decode_token_flops(blk.cfg, p + k - 1)
-                for p, k in toks)
+    count = families.of(blk.cfg).decode_token_flops
+    flops = sum(count(blk.cfg, p + k - 1) for p, k in toks)
     return 100 * flops / (sum(runs) * 1e-9 * run.peaks.bf16_flops)

@@ -1,12 +1,12 @@
-"""Model step: model FLOPs of one training step (forward and backward,
-mLSTM chunk terms included, no recomputation; ``bench/flops.py``) over the
+"""Model step: model FLOPs of one training step (forward and backward, no
+recomputation; the count of the block's family, ``bench/families/``) over the
 device time of one step program times the chip's bf16 peak.  The step
 program is the one that took the most device time on a train chip; its
 whole runs inside the window are averaged (``bench/trace.py`` leaves out
 the runs the trace's start and stop cut)."""
 import sys
 
-from bench import flops as F
+from bench import families
 from bench import trace as T
 
 UNIT, LAYER, MOVES, SOURCE = "%", "model step", "train_tokens_per_s", \
@@ -18,8 +18,8 @@ def read(run):
     planes = run.devices_of("train")
     if blk is None or not planes or run.to_trace is None:
         return None
-    step_flops = F.xlstm_train_step_flops(blk.cfg, blk.mix["global_batch"],
-                                          blk.mix["seq_len"])
+    step_flops = families.of(blk.cfg).train_step_flops(
+        blk.cfg, blk.mix["global_batch"], blk.mix["seq_len"])
     shares = []
     for p in planes:
         runs = T.busiest_program(run.trace, p, *run.trace_window())

@@ -1,9 +1,9 @@
 """Kernels: the paged-attention decode kernel's least time (the larger of
 its FLOPs over the bf16 peak and its bytes over HBM bandwidth, from the
-cache lengths of the tokens decoded in the traced window; ``bench/flops.py``)
-over the summed device time of the ``paged_attention`` Pallas calls in the
-window."""
-from bench import flops as F
+cache lengths of the tokens decoded in the traced window, counted by the
+block's family, ``bench/families/``) over the summed device time of the
+``paged_attention`` Pallas calls in the window."""
+from bench import families
 from bench import trace as T
 
 UNIT, LAYER, MOVES, SOURCE = "%", "kernels", "itl_p95_s", "device_trace"
@@ -20,7 +20,6 @@ def read(run):
             for k, t in enumerate(s.times) if k >= 1 and run.tw0 <= t <= run.tw1]
     if not n or not ctxs:
         return None
-    work = F.paged_attention_call(blk.cfg, ctxs).scale(
-        blk.cfg["num_hidden_layers"])
+    work = families.of(blk.cfg).paged_attention_calls(blk.cfg, ctxs)
     least = work.least_time(run.peaks.bf16_flops, run.peaks.hbm_bw)[0]
     return 100 * least / (spent * 1e-9)

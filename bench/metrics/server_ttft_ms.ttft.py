@@ -1,10 +1,12 @@
 """Decode scheduler: mean over the window's requests of their sessions'
 ``first_token_t - submitted_t`` as the scheduler records them: the wait
-for a slot plus the batch-1 prefill."""
+until the scheduler round that admits the request begins.  The scheduler
+stamps ``first_token_t`` with that round's start, so the batch-1 prefill
+(``prefill_ms.ttft``) is not in it."""
 import statistics
 
 UNIT, LAYER, MOVES, SOURCE = "ms", "runtime decode scheduler", \
-    "ttft_p90_s", "program_counter"
+    "ttft_p80_s", "program_counter"
 
 
 def read(run):

@@ -7,4 +7,17 @@ derivation the configuration's models are initialised with, and computes in
 code computes the control in the precision below the configuration's
 (``prec="fp8"``): every matrix product's operands are rounded to an
 8-bit float (e4m3) with a per-tensor scale first.
+
+A configuration names its module by its ``reference`` key.  A module for a
+family that trains exports ``init_params(cfg, seed)`` and
+``loss_and_grad(params, tokens, labels, cfg, prec)``; one for a family that
+serves exports ``logits_at(cfg, seed, tokens, idx, prec)``.
 """
+from __future__ import annotations
+
+import importlib
+
+
+def of(cfg):
+    """The reference module the configuration names."""
+    return importlib.import_module(f"{__name__}.{cfg['reference']}")

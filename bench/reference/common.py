@@ -1,6 +1,9 @@
 """Arithmetic shared by the references: matrix products in a chosen
-precision, the seeded weight draw, and RMSNorm."""
+precision, the seeded weight draw, RMSNorm, and the program's optimizer
+(its learning-rate schedule and AdamW steps)."""
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -75,3 +78,37 @@ def rmsnorm(x, scale, eps: float = 1e-6):
 def model_key(seed: int):
     """The root key a configuration's weights are drawn from."""
     return jax.random.PRNGKey(seed)
+
+
+# --------------------------------------------------------------- optimizer
+
+def lr_at(opt, step: int) -> float:
+    warm = min(step / max(opt["warmup_steps"], 1), 1.0)
+    prog = min(max((step - opt["warmup_steps"])
+                   / max(opt["total_steps"] - opt["warmup_steps"], 1), 0.0),
+               1.0)
+    cos = opt["min_lr_frac"] + (1 - opt["min_lr_frac"]) * 0.5 * (
+        1 + np.cos(np.pi * prog))
+    return opt["lr"] * warm * cos
+
+
+@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "wd", "clip"))
+def adamw(params, grads, m, v, step, lr, b1, b2, eps, wd, clip):
+    """AdamW with global-norm clipping; decoupled decay on every leaf of
+    two or more dimensions; parameters stored in bfloat16 as the
+    configuration states.  Returns (params, m, v, pre-clip norm, clipped
+    gradient)."""
+    gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
+    scale = jnp.minimum(1.0, clip / (gnorm + 1e-9))
+    g = jax.tree.map(lambda t: t * scale, grads)
+    m = jax.tree.map(lambda a, b: b1 * a + (1 - b1) * b, m, g)
+    v = jax.tree.map(lambda a, b: b2 * a + (1 - b2) * b * b, v, g)
+    bc1, bc2 = 1 - b1 ** step, 1 - b2 ** step
+
+    def upd(p, mm_, vv):
+        delta = (mm_ / bc1) / (jnp.sqrt(vv / bc2) + eps)
+        if p.ndim >= 2:
+            delta = delta + wd * p
+        return to_bf16(p - lr * delta)
+
+    return jax.tree.map(upd, params, m, v), m, v, gnorm, g

@@ -1,4 +1,5 @@
-"""Plain reference of the xLSTM[7:1] language model and its AdamW steps.
+"""Plain reference of the xLSTM[7:1] language model: its loss and gradient
+(the AdamW steps are ``common.adamw``).
 
 mLSTM in its parallel (quadratic) stabilized form, sLSTM as a step-by-step
 recurrence, both in float32.  The weights are drawn from the seed with the
@@ -192,37 +193,3 @@ def loss_and_grad(params, tokens, labels, cfg, prec="f32", rows: int = 2):
         total = total + l
         grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
     return total, grads
-
-
-# --------------------------------------------------------------- optimizer
-
-def lr_at(opt, step: int) -> float:
-    warm = min(step / max(opt["warmup_steps"], 1), 1.0)
-    prog = min(max((step - opt["warmup_steps"])
-                   / max(opt["total_steps"] - opt["warmup_steps"], 1), 0.0),
-               1.0)
-    cos = opt["min_lr_frac"] + (1 - opt["min_lr_frac"]) * 0.5 * (
-        1 + np.cos(np.pi * prog))
-    return opt["lr"] * warm * cos
-
-
-@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "wd", "clip"))
-def adamw(params, grads, m, v, step, lr, b1, b2, eps, wd, clip):
-    """AdamW with global-norm clipping; decoupled decay on every leaf of
-    two or more dimensions; parameters stored in bfloat16 as the
-    configuration states.  Returns (params, m, v, pre-clip norm, clipped
-    gradient)."""
-    gnorm = jnp.sqrt(sum(jnp.sum(g * g) for g in jax.tree.leaves(grads)))
-    scale = jnp.minimum(1.0, clip / (gnorm + 1e-9))
-    g = jax.tree.map(lambda t: t * scale, grads)
-    m = jax.tree.map(lambda a, b: b1 * a + (1 - b1) * b, m, g)
-    v = jax.tree.map(lambda a, b: b2 * a + (1 - b2) * b * b, v, g)
-    bc1, bc2 = 1 - b1 ** step, 1 - b2 ** step
-
-    def upd(p, mm_, vv):
-        delta = (mm_ / bc1) / (jnp.sqrt(vv / bc2) + eps)
-        if p.ndim >= 2:
-            delta = delta + wd * p
-        return to_bf16(p - lr * delta)
-
-    return jax.tree.map(upd, params, m, v), m, v, gnorm, g

@@ -27,6 +27,7 @@ import http.client
 import importlib.util
 import json
 import os
+import re
 import shutil
 import statistics
 import sys
@@ -366,18 +367,33 @@ def quantile(xs, q: float) -> float:
     return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
 
 
-def serve_metrics(ld: Load):
+#: a serve cell's tails, named by what they read and their percentile:
+#: ``ttft_p80_s`` is the 80th percentile of the times to first token
+TAIL_METRIC = re.compile(r"(ttft|itl)_p(\d+)_s$")
+
+
+def serve_metrics(ld: Load, names=()):
+    """The tails that ``names`` ask for (as ``TAIL_METRIC`` reads a name)
+    over the window's requests, with their counts.  A time to first token
+    runs from the request's due time; a request that failed counts as
+    infinite.  The inter-token gaps are those between a request's
+    successive SSE tokens."""
     meas = ld.measured
-    ttft = [(s.times[0] - s.t_due) if (s.done and s.times) else float("inf")
-            for s in meas]
-    gaps = [b - a for s in meas for a, b in zip(s.times, s.times[1:])]
+    samples = {
+        "ttft": [(s.times[0] - s.t_due) if (s.done and s.times)
+                 else float("inf") for s in meas],
+        "itl": [b - a for s in meas for a, b in zip(s.times, s.times[1:])]}
     failed = sum(1 for s in meas if not s.done)
     late = [s.t_send - s.t_due for s in meas if s.t_send]
-    return {"ttft_p90_s": quantile(ttft, 0.9),
-            "itl_p95_s": quantile(gaps, 0.95) if gaps else float("inf"),
-            "n": len(meas), "failed": failed, "n_gaps": len(gaps),
-            "late_max_s": max(late) if late else None,
-            "late_p50_s": statistics.median(late) if late else None}
+    out = {"n": len(meas), "failed": failed, "n_gaps": len(samples["itl"]),
+           "late_max_s": max(late) if late else None,
+           "late_p50_s": statistics.median(late) if late else None}
+    for name in names:
+        m = TAIL_METRIC.match(name)
+        if m:
+            xs = samples[m[1]]
+            out[name] = quantile(xs, int(m[2]) / 100) if xs else float("inf")
+    return out
 
 
 def serve_sample(ld: Load, seed: int, n: int = 8):
@@ -482,6 +498,7 @@ def run(argv=None, *, require_tpu: bool = True,
     if cell is None:
         raise SystemExit(f"no workload {args.workload!r} in {bench_path}")
     blocks = cell_blocks(cells_dir or bench_dir, cell)
+    e2e_decl = cell_metrics(bench, args.workload, "end_to_end", ())
     # the program's keys take 32 bits; larger seeds wrap
     pseed = args.seed % (1 << 32)
 
@@ -597,9 +614,9 @@ def run(argv=None, *, require_tpu: bool = True,
         sessions = None
         if ld is not None:
             ld.wait_measured(w1 + DRAIN_S)
-            sm = serve_metrics(ld)
-            e2e["ttft_p90_s"] = sm["ttft_p90_s"]
-            e2e["itl_p95_s"] = sm["itl_p95_s"]
+            sm = serve_metrics(ld, [m["name"] for m in e2e_decl])
+            e2e.update({m["name"]: sm[m["name"]] for m in e2e_decl
+                        if TAIL_METRIC.match(m["name"])})
             attempted += sm["n"]
             failed += sm["failed"]
             rt = daemon.runtime(serve.app)
@@ -650,8 +667,7 @@ def run(argv=None, *, require_tpu: bool = True,
                 "device_ops": trace_lib.top_ops(tr, planes, lo, hi),
                 "idle_gaps": trace_lib.attribute_gaps(gaps, in_win,
                                                       to_trace)}
-            e2e_names = {m["name"] for m in cell_metrics(
-                bench, args.workload, "end_to_end", ())}
+            e2e_names = {m["name"] for m in e2e_decl}
             for m in cell_metrics(bench, args.workload, "per_layer",
                                   e2e_names):
                 value = load_reader(bench_dir, m["name"]).read(data)
@@ -706,7 +722,6 @@ def run(argv=None, *, require_tpu: bool = True,
             gateway.stop()
         daemon.stop()
 
-    e2e_decl = cell_metrics(bench, args.workload, "end_to_end", ())
     if args.trace:
         metrics = per_layer
     else:

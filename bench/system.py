@@ -1,13 +1,18 @@
 """The system under test, as a tenant reaches it: the program's daemon,
 gateway and job specification, built from a configuration file's sizes.
 
-This is the only module of the benchmark that imports the program.  What a
-configuration file states and the program cannot be told (a width it fixes
-in code) is checked here, so that the program runs what the file says."""
+The program is built here and in the family modules this module loads
+(``bench/families/``); elsewhere a run reads only its layer-scope names
+(``bench/scopes.py``), and ``bench/described_compile.py`` compiles it for
+a described chip.  What a configuration file states and the
+program cannot be told (a width it fixes in code) is checked in its family
+module, so that the program runs what the file says."""
 from __future__ import annotations
 
 import os
 import sys
+
+from bench import families
 
 
 def import_program(root: str):
@@ -15,36 +20,9 @@ def import_program(root: str):
 
 
 def model_config(cfg):
-    from repro.models.config import AttentionConfig, ModelConfig, XLSTMConfig
-    if cfg["family"] == "xlstm":
-        assert cfg["conv_width"] == 4, "the program's mLSTM conv is width 4"
-        assert abs(cfg["slstm_ff_factor"] - 4 / 3) < 1e-9, \
-            "the program's sLSTM up-projection is 4/3"
-        return ModelConfig(
-            name=cfg["name"], family="xlstm",
-            n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-            vocab_size=cfg["vocab_size"], d_ff=0,
-            xlstm=XLSTMConfig(n_heads=cfg["num_heads"],
-                              proj_factor=cfg["proj_factor"],
-                              qk_factor=cfg["qk_factor"],
-                              slstm_every=cfg["slstm_every"],
-                              chunk=cfg["chunk_size"]),
-            tie_embeddings=cfg["tie_word_embeddings"],
-            param_dtype=cfg["param_dtype"])
-    if cfg["family"] == "dense":
-        assert cfg["hidden_act"] == "silu" and cfg["rms_norm_eps"] == 1e-6
-        H = cfg["num_attention_heads"]
-        return ModelConfig(
-            name=cfg["name"], family="dense",
-            n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-            vocab_size=cfg["vocab_size"], d_ff=cfg["intermediate_size"],
-            attention=AttentionConfig(
-                n_heads=H, n_kv_heads=cfg["num_key_value_heads"],
-                head_dim=cfg["hidden_size"] // H,
-                rope_theta=cfg["rope_theta"]),
-            tie_embeddings=cfg["tie_word_embeddings"],
-            param_dtype=cfg["param_dtype"])
-    raise ValueError(f"no model for family {cfg['family']!r}")
+    """The program's model configuration, built by the module of the
+    configuration's ``family`` (``bench/families/``)."""
+    return families.of(cfg).model_config(cfg)
 
 
 def train_job(cfg, mix, seed: int):

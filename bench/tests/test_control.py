@@ -89,19 +89,31 @@ def test_benchmark_configs_have_their_limits():
                                  else set())
             assert need <= named, cell["name"]
             assert cfg["limits"], cell["name"]
+            # a served model is judged by its logit gap, never only shown
+            if kind == "serve":
+                assert "logit_gap" in cfg["limits"], cell["name"]
 
 
 def test_seeds_share_their_work():
+    """Every seed offers the same requests at the same times; the seed
+    draws only the prompts' token ids."""
     mix = _mix("chat_tiny")
     a = load.serve_requests(mix, 256, 1, 10.0, 5.0)
     b = load.serve_requests(mix, 256, 2 ** 31 + 7, 10.0, 5.0)
-    for key in (lambda r: len(r.prompt), lambda r: r.max_new_tokens):
-        assert sorted(map(key, a)) == sorted(map(key, b))
+    shape = lambda rs: [(len(r.prompt), r.max_new_tokens, r.due_s,
+                         r.measured) for r in rs]
+    assert shape(a) == shape(b)
     assert [r.prompt for r in a] != [r.prompt for r in b]
     assert all(r.due_s < 10.0 for r in a if r.measured)
+    # the order is the mix's own: another order_seed puts the same lengths
+    # and gaps in another order
+    c = load.serve_requests(dict(mix, order_seed=1), 256, 1, 10.0, 5.0)
+    assert shape(c) != shape(a)
+    for key in (lambda r: len(r.prompt), lambda r: r.max_new_tokens):
+        assert sorted(map(key, a)) == sorted(map(key, c))
     gaps = lambda rs: np.sort(np.diff(
         [r.due_s for r in rs if r.measured] + [10.0]))
-    assert np.allclose(gaps(a), gaps(b))
+    assert np.allclose(gaps(a), gaps(c))
 
 
 def test_training_rows_all_differ():
