@@ -12,9 +12,11 @@ control (the reference in float8, put in the program's place) against the
 reference, and for a train cell the fault of half the batch left out, the
 mean taken over the rest, and a witness: the reference with its matrix
 products' operands in bfloat16, the configuration's own precision.  ``--rates`` instead offers the cell's serve mix
-at each rate in turn to one serve block and reports the tails, the
-tokens completed per second and the KV pages free in the window, to find
-the knee and the share of the pool that the mix fills."""
+at each rate in turn to the cell's serve block, with the cell's train
+blocks stepping beside it under autostep, and reports the tails, the tokens
+completed per second, the KV pages free and the train blocks' tokens/s in
+the window, to find the knee and the share of the pool that the mix
+fills."""
 from __future__ import annotations
 
 import argparse
@@ -144,8 +146,10 @@ def serve_readings(daemon, gateway, blk, seeds, control_seeds, seconds, out):
                 print(json.dumps(rec), flush=True)
 
 
-def sweep(daemon, gateway, blk, rates, seconds, out):
+def sweep(daemon, gateway, blk, trains, rates, seconds, out):
     run.setup_serve(daemon, blk, 0, gateway)
+    for b in trains:
+        run.setup_train(daemon, b, 0)
     rt = daemon.runtime(blk.app)
     for i, rate in enumerate(rates):
         before = dict(rt.sessions.stats())
@@ -156,8 +160,13 @@ def sweep(daemon, gateway, blk, rates, seconds, out):
         sm = run.serve_metrics(ld, SWEEP_TAILS)
         toks = sum(1 for s in ld.sent for t in s.times
                    if w0 <= t <= w0 + seconds)
+        wall0 = w0 + time.time() - time.perf_counter()
         rec = {"rate_per_s": rate, **sm,
                "tokens_per_s_in_window": toks / seconds,
+               "train_tokens_per_s": {
+                   b.tenant: run.train_rate(daemon, b, wall0,
+                                            wall0 + seconds)[0]
+                   for b in trains},
                # the KV pool's free pages, read every quarter second of
                # the window: the pool in use is the total less these
                "free_pages_min": min(free),
@@ -196,21 +205,24 @@ def main(argv=None) -> int:
     system.import_program(ROOT)
     daemon = system.start_daemon(jax.devices()[:cell["chips"]],
                                  os.path.join(ROOT, ".bench_out", "ckpt"))
-    gateway = system.start_gateway(daemon, "serve-tenant", run.TOKEN)
+    serve = next((b for b in blocks if b.kind == "serve"), None)
+    gateway = (system.start_gateway(daemon, serve.tenant, run.TOKEN)
+               if serve is not None else None)
     out = []
     try:
-        blk = blocks[0]
         if args.rates:
-            blk.tenant = "serve-tenant"
-            sweep(daemon, gateway, blk, args.rates, args.seconds, out)
-        elif blk.kind == "train":
-            train_readings(daemon, blk, args.seeds, args.control_seeds, out)
+            sweep(daemon, gateway, serve,
+                  [b for b in blocks if b.kind == "train"], args.rates,
+                  args.seconds, out)
+        elif serve is None:
+            train_readings(daemon, blocks[0], args.seeds,
+                           args.control_seeds, out)
         else:
-            blk.tenant = "serve-tenant"
-            serve_readings(daemon, gateway, blk, args.seeds,
+            serve_readings(daemon, gateway, serve, args.seeds,
                            args.control_seeds, args.seconds, out)
     finally:
-        gateway.stop()
+        if gateway is not None:
+            gateway.stop()
         daemon.stop()
     if args.out:
         with open(args.out, "w") as f:

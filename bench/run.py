@@ -524,11 +524,9 @@ def run(argv=None, *, require_tpu: bool = True,
     gateway = None
     ld = None
     try:
-        if any(b.kind == "serve" for b in blocks):
-            gateway = system.start_gateway(daemon, "serve-tenant", TOKEN)
-            for b in blocks:
-                if b.kind == "serve":
-                    b.tenant = "serve-tenant"
+        serve = next((b for b in blocks if b.kind == "serve"), None)
+        if serve is not None:
+            gateway = system.start_gateway(daemon, serve.tenant, TOKEN)
         prog: Dict[str, Dict] = {}
         for b in blocks:
             if b.kind == "serve":
@@ -544,7 +542,6 @@ def run(argv=None, *, require_tpu: bool = True,
                   for b in trains}
             for b in trains:
                 wait_step_events(daemon, b.app, n0[b.app] + 1)
-        serve = next((b for b in blocks if b.kind == "serve"), None)
         if serve is not None:
             vocab = serve.cfg["vocab_size"]
             reqs = load.serve_requests(serve.mix, vocab, args.seed,
@@ -584,6 +581,8 @@ def run(argv=None, *, require_tpu: bool = True,
             tw1 = time.perf_counter()
             ann.__exit__(None, None, None)
             jax.profiler.stop_trace()
+            log(f"profiler of {span:g} s stopped in "
+                f"{time.perf_counter() - tw1:.1f} s")
         time.sleep(max(0.0, w0 + args.seconds - time.perf_counter()))
         w1 = time.perf_counter()
         wall1 = time.time()
@@ -648,6 +647,8 @@ def run(argv=None, *, require_tpu: bool = True,
             by_id = {s[6]: s for s in spans}
             t_tr = time.perf_counter()
             tr = trace_lib.load(trace_dir)
+            trace_mb = sum(os.path.getsize(os.path.join(d, f)) for d, _, fs
+                           in os.walk(trace_dir) for f in fs) / 2 ** 20
             shutil.rmtree(trace_dir, ignore_errors=True)
             to_trace = (trace_lib.clock_map(tr["window"], (tw0, tw1))
                         if tr["window"] else None)
@@ -674,7 +675,9 @@ def run(argv=None, *, require_tpu: bool = True,
                 if value is not None:
                     per_layer[m["name"]] = {"value": value,
                                             "unit": m["unit"]}
-            log(f"trace of {device['window_s']:.1f} s read and reduced in "
+            n_ops = sum(len(d["ops"].code) for d in tr["devices"].values())
+            log(f"trace of {device['window_s']:.1f} s ({trace_mb:.0f} MiB, "
+                f"{n_ops} device ops) read and reduced in "
                 f"{time.perf_counter() - t_tr:.1f} s")
 
         # ------------------------------------------- free, then check
@@ -688,29 +691,37 @@ def run(argv=None, *, require_tpu: bool = True,
         daemon.stop()
         gc.collect()
 
+        # every block against the reference, under its own configuration's
+        # limits; in a cell of several blocks each number is named
+        # <tenant>.<number>.  Train blocks of one configuration and traffic
+        # share the seed, so they share one reference run.
         t_ref = time.perf_counter()
         nums: Dict[str, float] = {}
         limits: Dict[str, float] = {}
         loose: set = set()
+        refs: Dict[str, Dict] = {}
         for b in blocks:
-            if b.kind == "train" and "loss" not in nums:
-                with jax.default_device(b.devices[0]):
-                    ref = check.train_reference(b.cfg, b.mix, pseed)
+            if b.kind == "train":
+                key = json.dumps([b.cfg, b.mix], sort_keys=True)
+                if key not in refs:
+                    with jax.default_device(b.devices[0]):
+                        refs[key] = check.train_reference(b.cfg, b.mix,
+                                                          pseed)
+                ref = refs[key]
                 got, notes = check.train_numbers(
                     prog[b.tenant], ref, b.cfg.get("direction_leaf"))
                 log(f"train check {b.tenant}: program {prog[b.tenant]['losses']}"
                     f" losses, reference {ref['losses']}; {notes}")
-                nums.update(got)
-                limits.update(b.cfg["limits"])
-                loose.update(b.cfg.get("not_compared", {}))
-            elif b.kind == "serve":
+            else:
                 with jax.default_device(b.devices[0]):
                     gaps = check.serve_reference(b.cfg, pseed, sample)
                 log(f"serve check: {len(sample)} requests, {len(gaps)} "
                     f"served tokens compared")
-                nums.update(check.serve_numbers(gaps))
-                limits.update({"logit_gap": b.cfg["limits"]["logit_gap"]}
-                              if "logit_gap" in b.cfg["limits"] else {})
+                got = check.serve_numbers(gaps)
+            pre = f"{b.tenant}." if len(blocks) > 1 else ""
+            nums.update({pre + k: v for k, v in got.items()})
+            limits.update({pre + k: v for k, v in b.cfg["limits"].items()})
+            loose.update(pre + k for k in b.cfg.get("not_compared", {}))
         checks, ok = check.judge(nums, limits, loose)
         ok = ok and compiles_in_window == 0 and failed == 0
         log(f"reference and comparison took {time.perf_counter() - t_ref:.1f}"

@@ -6,14 +6,15 @@ The whole runs of the step program in the traced window
 charged to the layer scope the program's own compiled HLO gives its
 instruction (``repro.obs.programs``: ``{instruction name: scope}``, built
 on first request by one compile of the step with fresh metadata).  Returns
-milliseconds per step for each scope of ``repro.models.LAYER_SCOPES`` and
-``unscoped``.
+milliseconds per step for each scope of ``repro.models.LAYER_SCOPES`` that
+owns an instruction of this step, None for each scope that owns none (the
+layer of another family: an xLSTM step has no ``mla``), and ``unscoped``.
 
 It reports nothing (None, and says why on stderr) where the join cannot be
 trusted: a program without the map, a module name other than the traced
-program's, a scope that owns no instruction (metadata from a build without
-the scopes), or op names the map lacks on more than 1% of the step's device
-time."""
+program's, no scope of ``LAYER_SCOPES`` owning any instruction (metadata
+from a build without the scopes), or op names the map lacks on more than 1%
+of the step's device time."""
 from __future__ import annotations
 
 import sys
@@ -32,7 +33,7 @@ def log(msg: str) -> None:
     print(f"layer scopes: {msg}", file=sys.stderr, flush=True)
 
 
-def layer_ms(run) -> Optional[Dict[str, float]]:
+def layer_ms(run) -> Optional[Dict[str, Optional[float]]]:
     blk = run.block_of("train")
     if blk is None or run.trace is None or run.to_trace is None:
         return None
@@ -49,11 +50,13 @@ def layer_ms(run) -> Optional[Dict[str, float]]:
         return None
     log(f"map of {len(smap.scope)} instructions of {smap.module} in "
         f"{time.perf_counter() - t0:.1f} s")
-    owned = set(smap.scope.values())
-    if not owned.issuperset(LAYER_SCOPES):
-        log(f"scopes {sorted(set(LAYER_SCOPES) - owned)} own no instruction:"
-            f" stale metadata")
+    owned = set(smap.scope.values()) & set(LAYER_SCOPES)
+    if not owned:
+        log(f"no scope of {LAYER_SCOPES} owns an instruction: stale metadata")
         return None
+    if len(owned) < len(LAYER_SCOPES):
+        log(f"scopes {sorted(set(LAYER_SCOPES) - owned)} own no instruction "
+            f"of this step: they read nothing")
     ids = {d.id for d in blk.devices}
     planes = [p for p in run.trace["devices"]
               if p.rsplit(":", 1)[-1].isdigit()
@@ -101,4 +104,5 @@ def layer_ms(run) -> Optional[Dict[str, float]]:
     if not per_plane:
         return None
     mean = np.mean(per_plane, axis=0)
-    return {n: float(v) for n, v in zip(names, mean)}
+    return {n: float(v) if n in owned or n == UNSCOPED else None
+            for n, v in zip(names, mean)}
