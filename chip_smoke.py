@@ -330,7 +330,9 @@ def kernel_check(cfg, rt) -> None:
             table[b, j] = free.pop()
     q, k_pages, v_pages, tbl, sl = jax.device_put(
         (q, k_pages, v_pages, table, lens), dev)
-    attend = jax.jit(ops.paged_attention)
+    # one layer's pool: the kernel reads layer 0 of a stack of one
+    attend = jax.jit(lambda q, k, v, t, n: ops.paged_attention(
+        q, k[None], v[None], t, n, 0))
     got = np.asarray(attend(q, k_pages, v_pages, tbl, sl), np.float32)
     check("tpu_custom_call" in attend.lower(q, k_pages, v_pages, tbl, sl)
           .compile().as_text(), "paged_attention did not lower to Pallas")

@@ -237,33 +237,35 @@ def decode_attention(q, k_cache, v_cache, cache_len, *, scale=None,
     return o.reshape(B, Hq, 1, Dv).astype(q.dtype)
 
 
-def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *,
+def paged_attention(q, k_pages, v_pages, page_table, seq_lens, layer, *,
                     scale=None, impl: str = "auto"):
-    """Decode attention against a paged KV pool (continuous batching).
+    """Decode attention against one layer of a stacked paged KV pool
+    (continuous batching).
 
-    q: (B, Hq, 1, D); pools: (n_pages, page, Hkv, D|Dv);
+    q: (B, Hq, 1, D); pools: (L, n_pages, page, Hkv, D|Dv);
     page_table: (B, maxp) int32; seq_lens: (B,) int32 — valid entries per
-    slot (the new token's K/V already written at position seq_lens-1).
-    Returns (B, Hq, 1, Dv).
+    slot (the new token's K/V already written at position seq_lens-1);
+    layer: () int32, the pool to attend in.  Returns (B, Hq, 1, Dv).
 
     Sequence position ``p`` of slot ``b`` lives at row ``p % page`` of page
-    ``page_table[b, p // page]``, so the gathered view reproduces the dense
-    cache layout and the masked softmax below is ``decode_attention`` with a
-    per-slot length vector instead of one scalar ``cache_len``.
+    ``page_table[b, p // page]`` of pool ``layer``, so the gathered view
+    reproduces the dense cache layout and the masked softmax below is
+    ``decode_attention`` with a per-slot length vector instead of one
+    scalar ``cache_len``.
     """
     B, Hq, _, D = q.shape
-    _, page, Hkv, Dv = v_pages.shape
+    _, _, page, Hkv, Dv = v_pages.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(D)
     if _use_pallas(impl):
         from repro.kernels import paged_attention as _pa
         o = _pa.paged_attention_pallas(
-            q[:, :, 0], k_pages, v_pages, page_table, seq_lens, scale=scale,
-            interpret=(jax.default_backend() != "tpu"))
+            q[:, :, 0], k_pages, v_pages, page_table, seq_lens, layer,
+            scale=scale, interpret=(jax.default_backend() != "tpu"))
         return o[:, :, None]
     G = Hq // Hkv
     S = page_table.shape[1] * page
-    k = k_pages[page_table].reshape(B, S, Hkv, D).swapaxes(1, 2)
-    v = v_pages[page_table].reshape(B, S, Hkv, Dv).swapaxes(1, 2)
+    k = k_pages[layer, page_table].reshape(B, S, Hkv, D).swapaxes(1, 2)
+    v = v_pages[layer, page_table].reshape(B, S, Hkv, Dv).swapaxes(1, 2)
     qf = q.astype(jnp.float32).reshape(B, Hkv, G, D)
     s = jnp.einsum("bhgd,bhkd->bhgk", qf, k.astype(jnp.float32)) * scale
     pos = jnp.arange(S)

@@ -129,15 +129,18 @@ def attention_fwd(p, x, a: AttentionConfig, *, positions, cache=None,
 
 
 def paged_attention_fwd(p, x, a: AttentionConfig, *, pages, page_table,
-                        seq_lens):
+                        seq_lens, layer):
     """Decode one token per slot against a paged KV pool (continuous
-    batching).  x: (B, 1, d); pages: dict(k/v: (n_pages, page, Hkv, D|Dv));
-    page_table: (B, maxp) int32; seq_lens: (B,) int32 — tokens already
-    cached per slot.  The new token's K/V is written at position
+    batching).  x: (B, 1, d); pages: dict(k/v: (L, n_pages, page, Hkv,
+    D|Dv)), every layer's pool stacked; page_table: (B, maxp) int32;
+    seq_lens: (B,) int32 — tokens already cached per slot; layer: () int32.
+    The new token's K/V is written into pool ``layer`` at position
     ``seq_lens[b]`` (its page must already be allocated in the table), then
     the slot attends over ``seq_lens + 1`` entries — the exact analogue of
     the dense decode branch in ``attention_fwd``, with per-slot positions
-    instead of one scalar ``cache_len``.  Returns (out, new_pages)."""
+    instead of one scalar ``cache_len``.  Returns (out, new_pages): the
+    stacked pools with this layer's rows written, in place where ``pages``
+    is a loop carry."""
     B, S, _ = x.shape
     H, Hkv, D, vd = a.n_heads, a.n_kv_heads, a.head_dim, a.v_dim
     q = (x @ p["wq"]).reshape(B, S, H, D)
@@ -148,15 +151,17 @@ def paged_attention_fwd(p, x, a: AttentionConfig, *, pages, page_table,
     k = apply_rope(k.swapaxes(1, 2), positions, a.rope_theta)  # (B,Hkv,1,D)
     v = v.swapaxes(1, 2)
 
-    page = pages["k"].shape[1]
-    # flat pool row of each slot's write position; inactive slots (their
-    # table rows all point at the reserved trash page 0) scatter harmlessly
-    row = page_table[jnp.arange(B), seq_lens // page] * page + seq_lens % page
-    k_pool = pages["k"].reshape(-1, Hkv, D).at[row].set(
-        k[:, :, 0].astype(pages["k"].dtype)).reshape(pages["k"].shape)
-    v_pool = pages["v"].reshape(-1, Hkv, vd).at[row].set(
-        v[:, :, 0].astype(pages["v"].dtype)).reshape(pages["v"].shape)
-    o = ops.paged_attention(q, k_pool, v_pool, page_table, seq_lens + 1)
+    page = pages["k"].shape[2]
+    # each slot's write position; inactive slots (their table rows all
+    # point at the reserved trash page 0) scatter harmlessly
+    ids = page_table[jnp.arange(B), seq_lens // page]
+    rows = seq_lens % page
+    k_pool = pages["k"].at[layer, ids, rows].set(
+        k[:, :, 0].astype(pages["k"].dtype))
+    v_pool = pages["v"].at[layer, ids, rows].set(
+        v[:, :, 0].astype(pages["v"].dtype))
+    o = ops.paged_attention(q, k_pool, v_pool, page_table, seq_lens + 1,
+                            layer)
     o = o.swapaxes(1, 2).reshape(B, S, H * vd)
     return o @ p["wo"], {"k": k_pool, "v": v_pool}
 

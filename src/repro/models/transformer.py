@@ -126,14 +126,14 @@ def shared_extra_init(key, cfg: ModelConfig, dtype):
 # ---------------------------------------------------------------------------
 
 def _dense_sublayer_fwd(p, x, cfg, *, positions, cache, cache_len, causal=None,
-                        page_table=None, seq_lens=None):
+                        page_table=None, seq_lens=None, layer=None):
     h = apply_norm(p["ln1"], x, cfg.norm)
     # `is not None`: an all-zeros page table is a valid (trash-only) table
     if page_table is not None:
         a, new_cache = paged_attention_fwd(p["attn"], h, cfg.attention,
                                            pages=cache,
                                            page_table=page_table,
-                                           seq_lens=seq_lens)
+                                           seq_lens=seq_lens, layer=layer)
     elif cfg.attention.is_mla:
         a, new_cache = mla_fwd(p["attn"], h, cfg.attention,
                                positions=positions, cache=cache,
@@ -149,13 +149,13 @@ def _dense_sublayer_fwd(p, x, cfg, *, positions, cache, cache_len, causal=None,
 
 
 def _moe_sublayer_fwd(p, x, cfg, *, positions, cache, cache_len,
-                      page_table=None, seq_lens=None):
+                      page_table=None, seq_lens=None, layer=None):
     h = apply_norm(p["ln1"], x, cfg.norm)
     if page_table is not None:
         a, new_cache = paged_attention_fwd(p["attn"], h, cfg.attention,
                                            pages=cache,
                                            page_table=page_table,
-                                           seq_lens=seq_lens)
+                                           seq_lens=seq_lens, layer=layer)
     elif cfg.attention.is_mla:
         a, new_cache = mla_fwd(p["attn"], h, cfg.attention,
                                positions=positions, cache=cache,
@@ -171,14 +171,17 @@ def _moe_sublayer_fwd(p, x, cfg, *, positions, cache, cache_len,
 
 
 def group_fwd(gp, x, cfg: ModelConfig, *, positions, cache, cache_len, extra,
-              page_table=None, seq_lens=None):
-    """Returns (x, aux, new_cache).  ``cache`` is this group's cache (or None)."""
+              page_table=None, seq_lens=None, layer=None):
+    """Returns (x, aux, new_cache).  ``cache`` is this group's cache (or
+    None); on the paged path it is every group's stacked pool, of which
+    the group attends in and writes pool ``layer``."""
     fam = cfg.family
     aux = jnp.zeros((), jnp.float32)
     if fam in ("dense", "vlm"):
         x, nc = _dense_sublayer_fwd(gp, x, cfg, positions=positions,
                                     cache=cache, cache_len=cache_len,
-                                    page_table=page_table, seq_lens=seq_lens)
+                                    page_table=page_table, seq_lens=seq_lens,
+                                    layer=layer)
         return x, aux, nc
     if fam == "encoder":
         x, nc = _dense_sublayer_fwd(gp, x, cfg, positions=positions,
@@ -192,18 +195,18 @@ def group_fwd(gp, x, cfg: ModelConfig, *, positions, cache, cache_len, extra,
                                           positions=positions, cache=c_d,
                                           cache_len=cache_len,
                                           page_table=page_table,
-                                          seq_lens=seq_lens)
+                                          seq_lens=seq_lens, layer=layer)
             x, aux, nc_m = _moe_sublayer_fwd(gp["moe"], x, cfg,
                                              positions=positions, cache=c_m,
                                              cache_len=cache_len,
                                              page_table=page_table,
-                                             seq_lens=seq_lens)
+                                             seq_lens=seq_lens, layer=layer)
             nc = None if cache is None else {"dense": nc_d, "moe": nc_m}
             return x, aux, nc
         x, aux, nc = _moe_sublayer_fwd(gp, x, cfg, positions=positions,
                                        cache=cache, cache_len=cache_len,
                                        page_table=page_table,
-                                       seq_lens=seq_lens)
+                                       seq_lens=seq_lens, layer=layer)
         return x, aux, nc
     if fam == "xlstm":
         def m_step(x, inp):
@@ -427,29 +430,16 @@ def forward(params, cfg: ModelConfig, x, *, positions, cache=None,
 
     With ``page_table``/``seq_lens`` set, ``cache`` is the stacked paged
     pool from ``init_paged_cache`` and decode runs the paged-attention path
-    (the table and lengths are shared across groups; each group scans its
-    own pool slice).  Returns (logits (B, S, V), aux_loss, new_cache).
+    (the table and lengths are shared across groups).  Returns (logits
+    (B, S, V), aux_loss, new_cache).
     """
     extra = params.get("extra")
-
-    def body(carry, inp):
-        x, aux = carry
-        if cache is None:
-            gp, gc = inp, None
-        else:
-            gp, gc = inp
-        x, a, nc = group_fwd(gp, x, cfg, positions=positions, cache=gc,
-                             cache_len=cache_len, extra=extra,
-                             page_table=page_table, seq_lens=seq_lens)
-        return (x, aux + a), nc
-
-    if cfg.remat != "none":
-        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                  if cfg.remat == "dots" else None)
-        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
-
-    xs = params["layers"] if cache is None else (params["layers"], cache)
-    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    if page_table is not None:
+        x, aux, new_cache = _paged_layers(params, cfg, x, positions, cache,
+                                          page_table, seq_lens, extra)
+    else:
+        x, aux, new_cache = _layers(params, cfg, x, positions, cache,
+                                    cache_len, extra)
     with jax.named_scope("lm_head"):
         x = apply_norm(params["final_norm"], x, cfg.norm)
         head = (params["embed"].T if cfg.tie_embeddings
@@ -459,4 +449,52 @@ def forward(params, cfg: ModelConfig, x, *, positions, cache=None,
             logits = (jnp.tanh(logits / cfg.logits_softcap)
                       * cfg.logits_softcap)
         logits = shard_ctx.constrain_logits(logits)
-    return logits, aux, (None if cache is None else new_cache)
+    return logits, aux, new_cache
+
+
+def _layers(params, cfg: ModelConfig, x, positions, cache, cache_len, extra):
+    """The layer scan of training (``cache`` None), prefill and dense
+    decode: each group's cache slice enters as ``xs`` and its new cache
+    leaves stacked as ``ys``."""
+
+    def body(carry, inp):
+        x, aux = carry
+        if cache is None:
+            gp, gc = inp, None
+        else:
+            gp, gc = inp
+        x, a, nc = group_fwd(gp, x, cfg, positions=positions, cache=gc,
+                             cache_len=cache_len, extra=extra)
+        return (x, aux + a), nc
+
+    if cfg.remat != "none":
+        policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
+                  if cfg.remat == "dots" else None)
+        body = jax.checkpoint(body, policy=policy, prevent_cse=False)
+
+    xs = params["layers"] if cache is None else (params["layers"], cache)
+    (x, aux), new_cache = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
+    return x, aux, (None if cache is None else new_cache)
+
+
+def _paged_layers(params, cfg: ModelConfig, x, positions, pool, page_table,
+                  seq_lens, extra):
+    """The layer scan of paged decode.  The stacked pool rides in the
+    carry, one buffer for the whole loop: each group writes its slots' new
+    rows into its own layer of it in place and the kernel reads its pages
+    by layer index, so no layer's pool is sliced out and no stacked copy
+    is collected as ``ys``."""
+
+    def body(carry, inp):
+        x, aux, pool = carry
+        gp, layer = inp
+        x, a, pool = group_fwd(gp, x, cfg, positions=positions, cache=pool,
+                               cache_len=None, extra=extra,
+                               page_table=page_table, seq_lens=seq_lens,
+                               layer=layer)
+        return (x, aux + a, pool), None
+
+    (x, aux, pool), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), pool),
+        (params["layers"], jnp.arange(n_groups(cfg), dtype=jnp.int32)))
+    return x, aux, pool

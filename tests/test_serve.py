@@ -4,7 +4,9 @@ Layers under test, bottom-up:
 
 * the Pallas paged-attention kernel vs ``ref.attention`` in interpret
   mode, to float32 rounding (the kernel sweeps pages with an online
-  softmax, so its sums run in a different order than the reference's);
+  softmax, so its sums run in a different order than the reference's),
+  and on a stacked pool, by layer index, bit for bit against the same
+  arithmetic on that layer's pool alone;
 * ``DecodeScheduler`` semantics: bit-identical paged-vs-dense greedy
   decode, ``cache_len=0`` (the falsy-zero trap the analysis rule pack
   hunts), page-boundary crossing, full-pool admission refusal,
@@ -16,6 +18,7 @@ Layers under test, bottom-up:
 * checkpointed paged state: in-flight sessions survive preempt/resume,
   including resume on a different mesh geometry (subprocess).
 """
+import functools
 import json
 import os
 import subprocess
@@ -29,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops, ref
 from repro.kernels.paged_attention import paged_attention_pallas
@@ -120,8 +125,8 @@ def test_paged_kernel_bitwise_vs_ref_full_slots(B, Hq, Hkv, D, Dv, page,
     k_pages, v_pages, table, seq_lens = make_paged(
         KEY, B, Hkv, D, Dv, page, maxp, n_pages=B * maxp + 2, lens=lens)
     q = jax.random.normal(jax.random.fold_in(KEY, 9), (B, Hq, D))
-    got = paged_attention_pallas(q, k_pages, v_pages, table, seq_lens,
-                                 interpret=True)
+    got = paged_attention_pallas(q, k_pages[None], v_pages[None], table,
+                                 seq_lens, 0, interpret=True)
     kd = gather_dense(k_pages, table, B, S, Hkv)
     vd = gather_dense(v_pages, table, B, S, Hkv)
     want = ref.attention(q[:, :, None], kd, vd, causal=False)[:, :, 0]
@@ -143,10 +148,10 @@ def test_paged_kernel_ragged_lens_and_trash_page():
     k_pages = k_pages.at[0].set(1e4)
     v_pages = v_pages.at[0].set(-1e4)
     q = jax.random.normal(jax.random.fold_in(KEY, 2), (B, Hq, 1, D))
-    got = ops.paged_attention(q, k_pages, v_pages, table, seq_lens,
-                              impl="pallas")
-    want = ops.paged_attention(q, k_pages, v_pages, table, seq_lens,
-                               impl="jnp")
+    got = ops.paged_attention(q, k_pages[None], v_pages[None], table,
+                              seq_lens, 0, impl="pallas")
+    want = ops.paged_attention(q, k_pages[None], v_pages[None], table,
+                               seq_lens, 0, impl="jnp")
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-6, rtol=1e-5)
     for b, ln in enumerate(lens):             # vs truncated naive oracle
@@ -156,6 +161,100 @@ def test_paged_kernel_ragged_lens_and_trash_page():
                           causal=False)
         np.testing.assert_allclose(np.asarray(got[b:b + 1]), np.asarray(w),
                                    atol=3e-5, rtol=3e-4)
+
+
+def _one_pool_kernel(pt_ref, sl_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
+                     l_scr, acc_scr, *, pages_per_seq, page, groups, scale):
+    b, j = pl.program_id(0), pl.program_id(1)
+    seq_len = sl_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, -1.0e30)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * page < seq_len)
+    def _body():
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        pos = j * page + jax.lax.broadcasted_iota(
+            jnp.int32, (page, k.shape[1], 1), 0)
+        live = pos < seq_len
+        for g in range(groups):
+            q = q_ref[0, g].astype(jnp.float32)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True) * scale
+            s = jnp.where(live, s, -1.0e30)
+            m_prev = m_scr[g]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+            p = jnp.exp(s - m_new[None]) * live.astype(jnp.float32)
+            corr = jnp.exp(m_prev - m_new)
+            l_scr[g] = l_scr[g] * corr + jnp.sum(p, axis=0)
+            acc_scr[g] = acc_scr[g] * corr + jnp.sum(p * v, axis=0)
+            m_scr[g] = m_new
+
+    @pl.when(j == pages_per_seq - 1)
+    def _finalize():
+        for g in range(groups):
+            l = jnp.where(l_scr[g] == 0.0, 1.0, l_scr[g])
+            o_ref[0, g] = (acc_scr[g] / l).astype(o_ref.dtype)
+
+
+def paged_attention_one_pool(q, k_pages, v_pages, table, seq_lens):
+    """The paged kernel as it was before pools were stacked: the same
+    arithmetic on one layer's ``(P, page, Hkv, D)`` pool, its page ids the
+    whole block index.  q: (B, Hq, D).  Returns (B, Hq, Dv)."""
+    B, Hq, D = q.shape
+    _, page, Hkv, _ = k_pages.shape
+    Dv = v_pages.shape[-1]
+    G, maxp = Hq // Hkv, table.shape[1]
+    qg = q.reshape(B, Hkv, G, D).swapaxes(1, 2)
+
+    def kv_index(b, j, pt_ref, sl_ref):
+        last = jnp.maximum(sl_ref[b] - 1, 0) // page
+        return (pt_ref[b, jnp.minimum(j, last)], 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, maxp),
+        in_specs=[pl.BlockSpec((1, G, Hkv, D), lambda b, j, *_: (b, 0, 0, 0)),
+                  pl.BlockSpec((1, page, Hkv, D), kv_index),
+                  pl.BlockSpec((1, page, Hkv, Dv), kv_index)],
+        out_specs=pl.BlockSpec((1, G, Hkv, Dv),
+                               lambda b, j, *_: (b, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((G, Hkv, 1), jnp.float32),
+                        pltpu.VMEM((G, Hkv, 1), jnp.float32),
+                        pltpu.VMEM((G, Hkv, Dv), jnp.float32)])
+    kernel = functools.partial(_one_pool_kernel, pages_per_seq=maxp,
+                               page=page, groups=G, scale=1.0 / np.sqrt(D))
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, G, Hkv, Dv), q.dtype),
+        interpret=True)(table, seq_lens, qg, k_pages, v_pages)
+    return out.swapaxes(1, 2).reshape(B, Hq, Dv)
+
+
+@pytest.mark.parametrize("L", [1, 2, 5])
+def test_paged_kernel_layer_index_bitwise_vs_one_pool(L):
+    """The kernel on a stacked ``(L, P, page, Hkv, D)`` pool, told a layer,
+    equals the one-pool arithmetic on ``pool[layer]`` bit for bit, for
+    every layer: ragged fills, a slot at a page boundary, and an idle slot
+    whose table row is all trash page."""
+    B, Hq, Hkv, D, Dv, page, maxp = 5, 4, 2, 16, 8, 4, 3
+    lens = [1, 6, 8, 12, 0]          # mid-page / boundary / full / idle
+    n_pages = B * maxp + 1
+    ks = jax.random.split(jax.random.fold_in(KEY, 20 + L), 3)
+    k_pool = jax.random.normal(ks[0], (L, n_pages, page, Hkv, D), jnp.float32)
+    v_pool = jax.random.normal(ks[1], (L, n_pages, page, Hkv, Dv),
+                               jnp.float32)
+    _, _, table, seq_lens = make_paged(KEY, B, Hkv, D, Dv, page, maxp,
+                                       n_pages=n_pages, lens=lens)
+    q = jax.random.normal(ks[2], (B, Hq, D), jnp.float32)
+    for layer in range(L):
+        got = paged_attention_pallas(q, k_pool, v_pool, table, seq_lens,
+                                     jnp.int32(layer), interpret=True)
+        want = paged_attention_one_pool(q, k_pool[layer], v_pool[layer],
+                                        table, seq_lens)
+        assert np.array_equal(np.asarray(got), np.asarray(want)), layer
 
 
 def test_paged_decode_model_matches_dense_bitwise(cfg, params):

@@ -16,11 +16,14 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import deepseek_7b
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.fused_adamw import fused_adamw_update
 from repro.kernels.paged_attention import paged_attention_pallas
 from repro.kernels.rmsnorm import rmsnorm_pallas
 from repro.kernels.ssd_scan import ssd_scan_pallas
+from repro.models import model as model_lib
+from repro.serve.decode_scheduler import DecodeScheduler, paged_geometry
 from repro.train import quantized_state as qs
 
 
@@ -57,27 +60,57 @@ def compile_for(one_chip, fn, *shapes):
 
 
 def test_paged_attention_compiles_at_deepseek_7b_widths(one_chip):
-    # deepseek_7b: 32 heads (MHA), head_dim 128; page 16, 8 slots x 1024
-    B, H, D, page, maxp = 8, 32, 128, 16, 64
+    # deepseek_7b: 32 heads (MHA), head_dim 128; page 16, 8 slots x 1024,
+    # the pools of 16 layers stacked
+    B, H, D, page, maxp, L = 8, 32, 128, 16, 64, 16
     n_pages = B * maxp + 1
     bf = jnp.bfloat16
     c = compile_for(one_chip, paged_attention_pallas,
-                    ((B, H, D), bf), ((n_pages, page, H, D), bf),
-                    ((n_pages, page, H, D), bf), ((B, maxp), jnp.int32),
-                    ((B,), jnp.int32))
+                    ((B, H, D), bf), ((L, n_pages, page, H, D), bf),
+                    ((L, n_pages, page, H, D), bf), ((B, maxp), jnp.int32),
+                    ((B,), jnp.int32), ((), jnp.int32))
     # one page of K and V at a time: no whole-sequence VMEM scratch
     assert c.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
 def test_paged_attention_compiles_for_gqa(one_chip):
     # 4 query heads per kv head (mistral_nemo_12b's 32/8 grouping)
-    B, page, maxp, Hkv = 8, 16, 64, 8
+    B, page, maxp, Hkv, L = 8, 16, 64, 8, 4
     n_pages = B * maxp + 1
     bf = jnp.bfloat16
     compile_for(one_chip, paged_attention_pallas,
-                ((B, 32, 128), bf), ((n_pages, page, Hkv, 128), bf),
-                ((n_pages, page, Hkv, 128), bf), ((B, maxp), jnp.int32),
-                ((B,), jnp.int32))
+                ((B, 32, 128), bf), ((L, n_pages, page, Hkv, 128), bf),
+                ((L, n_pages, page, Hkv, 128), bf), ((B, maxp), jnp.int32),
+                ((B,), jnp.int32), ((), jnp.int32))
+
+
+def test_paged_decode_program_writes_the_pool_in_place(one_chip,
+                                                       monkeypatch):
+    """The serve cell's decode program (deepseek_7b's first 16 layers, 15
+    slots of 1024 tokens, page 16) keeps the donated KV pool in place: its
+    scratch holds no stacked copy of the pool, and its output aliases the
+    whole pool."""
+    # the model picks its Pallas kernels when it believes it is on a TPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = deepseek_7b.CONFIG.replace(n_layers=16)
+    slots, page = 15, 16
+    geo = paged_geometry(cfg, page_size=page, n_pages=0, max_slots=slots,
+                         max_seq_len=1024)
+    spec = lambda t: jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), t)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    pool = spec(jax.eval_shape(lambda: model_lib.init_paged_cache(
+        cfg, geo["n_pages"], page)))
+    sch = DecodeScheduler.__new__(DecodeScheduler)
+    sch.cfg, sch.sample, sch.page_size = cfg, False, page
+    dec = jax.jit(sch._make_decode(), donate_argnums=(2,)).lower(
+        spec(model_lib.abstract_params(cfg)), i32(slots, 1), pool,
+        i32(slots, geo["pages_per_seq"]), i32(slots)).compile()
+    mem = dec.memory_analysis()
+    k_pool = pool["k"].size * pool["k"].dtype.itemsize
+    assert pool["k"].shape == (16, 961, 16, 32, 128)
+    assert mem.temp_size_in_bytes < k_pool, mem
+    assert mem.alias_size_in_bytes >= 2 * k_pool, mem
 
 
 def test_flash_attention_compiles_32_heads_seq_2048(one_chip):
